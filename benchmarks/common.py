@@ -22,6 +22,7 @@ import json
 import logging
 import os
 import sys
+import tempfile
 import time
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence
@@ -141,6 +142,21 @@ def _load_cached(path: Path, force: bool) -> Optional[Dict]:
         path.unlink(missing_ok=True)
         return None
     return None if force else data
+
+
+@contextlib.contextmanager
+def throwaway_cache(prefix: str = "sweep_"):
+    """Point the results cache (`EXP_DIR`) at a fresh temporary directory
+    for the block, so a forced sweep neither reads nor leaves results in
+    experiments/sim/. Yields the temporary directory."""
+    global EXP_DIR
+    saved = EXP_DIR
+    with tempfile.TemporaryDirectory(prefix=prefix) as tmp:
+        EXP_DIR = Path(tmp)
+        try:
+            yield EXP_DIR
+        finally:
+            EXP_DIR = saved
 
 
 def evict_stale() -> List[str]:

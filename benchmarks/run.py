@@ -25,6 +25,8 @@ def _section(title: str):
 
 
 def main() -> None:
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="smaller workload counts / cycles")

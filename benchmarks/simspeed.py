@@ -37,7 +37,6 @@ from __future__ import annotations
 import argparse
 import json
 import platform
-import tempfile
 import time
 from pathlib import Path
 from typing import Dict, Sequence
@@ -46,7 +45,7 @@ import jax
 import numpy as np
 
 from benchmarks import common
-from repro import compat
+from repro import compat, compile_cache
 from repro.core import simulator as sim
 from repro.core import workloads as wl
 
@@ -107,17 +106,11 @@ def _xla_program_counts() -> Dict[str, int]:
 
 def _cold_sweep(cfg, policies, wls, n_cycles, warmup, stacked, tag):
     """force-run `run_sweep` into a throwaway cache dir; returns wall_s."""
-    saved_dir = common.EXP_DIR
-    with tempfile.TemporaryDirectory(prefix="simspeed_") as tmp:
-        common.EXP_DIR = Path(tmp)
-        try:
-            t0 = time.time()
-            common.run_sweep(cfg, policies, wls, n_cycles=n_cycles,
-                             warmup=warmup, tag=tag, force=True,
-                             stacked=stacked)
-            return time.time() - t0
-        finally:
-            common.EXP_DIR = saved_dir
+    with common.throwaway_cache(prefix="simspeed_"):
+        t0 = time.time()
+        common.run_sweep(cfg, policies, wls, n_cycles=n_cycles,
+                         warmup=warmup, tag=tag, force=True, stacked=stacked)
+        return time.time() - t0
 
 
 def measure_sweep(policies: Sequence[str], n_per_cat: int, n_cycles: int,
@@ -380,6 +373,7 @@ def measure_telemetry_gate(n_cycles: int = 280, warmup: int = 70) -> Dict:
 def main(sweep_scale: Dict = None, policy_scale: Dict = None,
          family_scale: Dict = None, event_scale: Dict = None,
          write: bool = True, summary_out: str = None) -> Dict:
+    compile_cache.enable()
     sweep_scale = sweep_scale or SWEEP_SCALE
     policy_scale = policy_scale or POLICY_SCALE
     family_scale = family_scale or FAMILY_SCALE

@@ -16,6 +16,7 @@ sys.path.insert(0, "src")
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import metrics as met
 from repro.core import policy
 from repro.core import simulator as sim
@@ -35,6 +36,7 @@ class Oldest(CentralizedPolicy):
 
 
 def main():
+    compile_cache.enable()
     # 4 CPU cores + 1 GPU sharing 2 memory channels, high-intensity mix
     cfg = SimConfig(n_cpu=4, n_channels=2, buf_entries=72, fifo_size=8,
                     dcs_size=4)

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs.base import RunConfig, reduced
 from repro.configs.registry import get_config
 from repro.serving import paged_lm
@@ -28,6 +29,7 @@ RUN = RunConfig(compute_dtype="float32")
 
 
 def main():
+    compile_cache.enable()
     cfg = reduced(get_config("qwen1.5-4b"), n_layers=2, d_model=64,
                   n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,
                   vocab_size=256)

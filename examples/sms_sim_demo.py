@@ -11,11 +11,13 @@ sys.path.insert(0, "src")
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import simulator as sim
 from repro.core.params import SimConfig
 
 
 def main():
+    compile_cache.enable()
     cfg = SimConfig(n_cpu=2, n_channels=1, buf_entries=28, fifo_size=6,
                     dcs_size=4)
     pool = {

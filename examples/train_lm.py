@@ -14,6 +14,7 @@ sys.path.insert(0, "src")
 
 import jax
 
+from repro import compile_cache
 from repro.configs.base import RunConfig, ShapeConfig, reduced
 from repro.configs.registry import get_config
 from repro.launch.mesh import make_local_mesh
@@ -21,6 +22,7 @@ from repro.train.trainer import StragglerPolicy, Trainer
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="xlstm-125m")
     ap.add_argument("--steps", type=int, default=300)

@@ -1,85 +1,47 @@
-"""Version-compat shims for the pinned jax (0.4.37).
+"""Shared adapters over the installed jax (0.9.0).
 
-Every deprecated/moved jax API the repo touches is funneled through this
-module, so a future jax bump is a one-file change:
-
-  * ``shard_map`` — lives at ``jax.experimental.shard_map.shard_map`` in the
-    pinned release (kwarg ``check_rep``) and at ``jax.shard_map`` (kwarg
-    ``check_vma``) after jax 0.6. The shim resolves whichever exists and
-    translates the check kwarg, so call sites can uniformly pass the modern
-    ``check_vma`` name.
-  * ``tree_map`` — ``jax.tree_map`` was removed; ``jax.tree_util.tree_map``
-    works on every release we care about (``jax.tree.map`` only post-0.4.25).
-  * jaxpr introspection types (``Jaxpr``/``ClosedJaxpr``) — moved from
-    ``jax.core`` to ``jax.extend.core``; plus the nested-jaxpr walkers the
-    perf-invariant tests share.
+  * ``make_mesh`` — `jax.make_mesh` builds meshes whose axes are Explicit
+    by default. The model, training and pipeline code is written for Auto
+    axes (sharding propagated by the compiler inside ``with mesh:``), so
+    every mesh in the repo is built through this helper.
+  * jaxpr introspection (``Jaxpr``/``ClosedJaxpr`` from ``jax.extend.core``)
+    plus the nested-jaxpr walkers the perf-invariant tests share.
+  * ``jit_cache_size`` — the number of compiled programs behind a jitted
+    function (a private accessor; the one-XLA-program gates count with it).
 """
 from __future__ import annotations
 
-import inspect
-from typing import Iterator, Tuple
+from typing import Iterator, Sequence, Tuple
 
 import jax
+from jax.extend.core import ClosedJaxpr, Jaxpr
+from jax.sharding import AxisType
 
 # ---------------------------------------------------------------------------
-# tree_map: one non-deprecated spelling for every supported release
-# ---------------------------------------------------------------------------
-
-tree_map = jax.tree_util.tree_map
-
-# ---------------------------------------------------------------------------
-# shard_map
+# meshes
 # ---------------------------------------------------------------------------
 
 
-def _resolve_shard_map():
-    """(impl, name_of_replication_check_kwarg) for this jax version."""
-    try:                                     # pinned 0.4.x location
-        from jax.experimental.shard_map import shard_map as impl
-    except ImportError:                      # jax >= 0.6: top-level
-        impl = jax.shard_map
-    params = inspect.signature(impl).parameters
-    for kw in ("check_vma", "check_rep"):
-        if kw in params:
-            return impl, kw
-    return impl, None
-
-
-_SHARD_MAP_IMPL, _CHECK_KW = _resolve_shard_map()
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True, **kw):
-    """`jax.shard_map` signature (modern `check_vma` kwarg), any jax version."""
-    if _CHECK_KW is not None and _CHECK_KW not in kw:
-        kw[_CHECK_KW] = check_vma
-    return _SHARD_MAP_IMPL(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]
+              ) -> jax.sharding.Mesh:
+    """`jax.make_mesh` with every axis Auto (compiler-propagated sharding)."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 # ---------------------------------------------------------------------------
-# jit cache introspection (private API, name has moved across releases)
+# jit cache introspection
 # ---------------------------------------------------------------------------
 
 
 def jit_cache_size(fn) -> int:
     """Number of distinct compiled programs behind a jitted function."""
-    for attr in ("_cache_size", "cache_size"):
-        size = getattr(fn, attr, None)
-        if size is not None:
-            return size() if callable(size) else size
-    raise AttributeError(
-        f"no jit cache-size accessor on {fn!r} for jax {jax.__version__}; "
-        f"update repro.compat.jit_cache_size")
+    return fn._cache_size()
 
 
 # ---------------------------------------------------------------------------
-# jaxpr introspection (moved out of jax.core)
+# jaxpr introspection
 # ---------------------------------------------------------------------------
-
-try:                                         # jax >= 0.4.33 new-style location
-    from jax.extend.core import ClosedJaxpr, Jaxpr  # noqa: F401
-except ImportError:                          # older releases
-    from jax.core import ClosedJaxpr, Jaxpr  # noqa: F401
 
 
 def sub_jaxprs(value) -> list:

@@ -96,6 +96,39 @@ def _run_cycles(step, skip_body, carry, t0: int, t1: int, unroll: int):
     return carry, steps
 
 
+def div_rn(a: jax.Array, b) -> jax.Array:
+    """f32 `a / b` rounded to nearest-even, bit-identical on every backend.
+
+    XLA:CPU divides as IEEE does; the TPU's f32 divide is not correctly
+    rounded (on a v5e, `avg_lat` and `rbl` taken with `/` differed from the
+    CPU's in some elements). Here the 24-bit significands are divided by
+    integer long division into 27 quotient bits plus a sticky bit, which
+    one exact-operand f32 add rounds once; the exponent is applied by exact
+    power-of-two products. For finite a >= 0 and b > 0 whose quotient is a
+    normal f32 or zero (a zero or subnormal `a` gives 0).
+    """
+    a = jnp.asarray(a, jnp.float32)
+    b = jnp.broadcast_to(jnp.asarray(b, jnp.float32), a.shape)
+    ia = jax.lax.bitcast_convert_type(a, jnp.int32)
+    ib = jax.lax.bitcast_convert_type(b, jnp.int32)
+    ea, eb = ia >> 23, ib >> 23
+    ma = (ia & 0x7FFFFF) | 0x800000
+    mb = (ib & 0x7FFFFF) | 0x800000
+    n, r = jnp.zeros_like(ma), ma
+    for _ in range(27):       # n = floor(ma / mb * 2**26), in [2**25, 2**27)
+        bit = r >= mb
+        n = 2 * n + bit.astype(jnp.int32)
+        r = 2 * jnp.where(bit, r - mb, r)
+    n = n | (r != 0).astype(jnp.int32)    # sticky bit below the guard bit
+    q = (n >> 16).astype(jnp.float32) * jnp.float32(65536.0) \
+        + (n & 0xFFFF).astype(jnp.float32)
+    pow2 = lambda e: jax.lax.bitcast_convert_type(
+        (jnp.clip(e, -126, 127) + 127) << 23, jnp.float32)
+    e = ea - eb - 26
+    q = q * pow2(e >> 1) * pow2(e - (e >> 1))
+    return jnp.where(ea == 0, jnp.float32(0.0), q)
+
+
 def _scan_and_measure(cfg: SimConfig, step, skip_body, carry, n_cycles: int,
                       warmup: int, unroll: int) -> Dict[str, jax.Array]:
     """Warmup run, stat snapshot, measured run, delta metrics.
@@ -122,12 +155,13 @@ def _scan_and_measure(cfg: SimConfig, step, skip_body, carry, n_cycles: int,
     d = lambda k: (st_f[k] if k in st_f else dram_f[k]).astype(jnp.float32) \
         - snap[k].astype(jnp.float32)
     completed = d("completed")
+    # ratios through `div_rn`, so the chip and the CPU report the same bits
     out = {
-        "ipc": d("insts_done") / cyc,
-        "bw": completed / cyc,                        # requests per cycle
-        "mpkc": d("emitted") / cyc * 1000.0,
-        "rbl": d("hits") / jnp.maximum(d("issued"), 1.0),
-        "avg_lat": d("sum_lat") / jnp.maximum(completed, 1.0),
+        "ipc": div_rn(d("insts_done"), cyc),
+        "bw": div_rn(completed, cyc),                 # requests per cycle
+        "mpkc": div_rn(d("emitted"), cyc) * 1000.0,
+        "rbl": div_rn(d("hits"), jnp.maximum(d("issued"), 1.0)),
+        "avg_lat": div_rn(d("sum_lat"), jnp.maximum(completed, 1.0)),
         "completed": completed,
         "emitted": d("emitted"),
         "outstanding_end": st_f["outstanding"].astype(jnp.float32),

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 
 PyTree = Any
 
@@ -71,7 +70,7 @@ def gpipe_apply(stage_fn: Callable[[PyTree, jax.Array], jax.Array],
     # params: stage axis sharded; x: replicated over `axis`
     pspec = jax.tree_util.tree_map(
         lambda a: P(*([axis] + [None] * (a.ndim - 1))), stage_params)
-    out = compat.shard_map(
+    out = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(pspec, P()), out_specs=P(),
         check_vma=False,

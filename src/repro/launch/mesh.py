@@ -3,6 +3,8 @@ from __future__ import annotations
 
 import jax
 
+from repro import compat
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: 16x16 = 256 chips (data, model).
@@ -14,7 +16,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return compat.make_mesh(shape, axes)
 
 
 def make_local_mesh(model: int = 1, data: int = 1):
@@ -22,4 +24,4 @@ def make_local_mesh(model: int = 1, data: int = 1):
     n = len(jax.devices())
     if model * data > n:
         model, data = 1, min(data, n)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return compat.make_mesh((data, model), ("data", "model"))

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig, RunConfig
 from repro.models.common import ParamDef, act_fn
 
@@ -170,7 +169,7 @@ def moe_apply(x: jax.Array, p: Dict[str, jax.Array], cfg: ModelConfig,
                 aux = jax.lax.pmean(aux, data_axes)
             return y2.reshape(Bl, Sl, d), aux
 
-        y, aux = compat.shard_map(
+        y, aux = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(pspec_x, pspec_r, pspec_w3, pspec_w3, pspec_w3),
             out_specs=(pspec_x, P()),

@@ -1,85 +1,58 @@
-"""Unit tests for `repro.compat` — the one-file jax version shim.
+"""Unit tests for `repro.compat` — adapters over the installed jax (0.9.0).
 
-These exist so the next jax bump fails HERE, loudly and attributably,
-instead of deep inside `moe.py`/`distributed.pipeline` at trace time:
-shard_map resolution + check-kwarg translation, tree_map, the jaxpr
-walkers the perf-invariant tests build on, and jit cache introspection.
+These exist so a jax upgrade fails HERE, loudly and attributably, instead
+of deep inside the model, pipeline or perf-invariant code: the Auto-axis
+mesh helper, the jaxpr walkers the perf-invariant tests build on, and jit
+cache introspection.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro import compat
+from repro.launch.mesh import make_local_mesh
 
 
 # ---------------------------------------------------------------------------
-# shard_map: resolution + kwarg translation
+# make_mesh: Auto axes (jax.make_mesh defaults to Explicit)
 # ---------------------------------------------------------------------------
 
-def test_resolve_shard_map_finds_an_impl():
-    impl, kw = compat._resolve_shard_map()
-    assert callable(impl)
-    assert kw in (None, "check_rep", "check_vma"), kw
-    # the module-level binding matches a fresh resolution
-    assert compat._CHECK_KW == kw
+@pytest.mark.parametrize("names", [("pod",), ("data", "model"),
+                                   ("pod", "data", "model")])
+def test_make_mesh_axes_are_auto(names):
+    mesh = compat.make_mesh((1,) * len(names), names)
+    assert mesh.axis_names == names
+    assert mesh.axis_types == (AxisType.Auto,) * len(names)
+    assert mesh.devices.size == 1
 
 
-@pytest.mark.parametrize("native_kw", ["check_rep", "check_vma"])
-def test_shard_map_translates_check_kwarg(monkeypatch, native_kw):
-    """Callers always pass the modern `check_vma`; the shim must hand the
-    pinned implementation whatever spelling it natively accepts."""
-    seen = {}
-
-    def fake_impl(f, mesh, in_specs, out_specs, **kw):
-        seen.update(kw, mesh=mesh)
-        return "mapped"
-
-    monkeypatch.setattr(compat, "_SHARD_MAP_IMPL", fake_impl)
-    monkeypatch.setattr(compat, "_CHECK_KW", native_kw)
-    out = compat.shard_map(lambda x: x, mesh="MESH", in_specs=(),
-                           out_specs=(), check_vma=False)
-    assert out == "mapped"
-    assert seen[native_kw] is False and seen["mesh"] == "MESH"
-    assert ("check_vma" in seen) == (native_kw == "check_vma")
+def test_make_local_mesh_is_auto():
+    mesh = make_local_mesh(model=1, data=1)
+    assert mesh.axis_names == ("data", "model")
+    assert set(mesh.axis_types) == {AxisType.Auto}
 
 
-def test_shard_map_no_check_kwarg_supported(monkeypatch):
-    """An impl with no replication-check kwarg gets none injected."""
-    seen = {}
+def test_make_mesh_grad_under_mesh_context():
+    """A sharding-constrained gather + grad under `with mesh:` — the
+    pattern that an Explicit-axis mesh refuses (embedding lookup of a
+    sharded table, differentiated)."""
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    emb = jnp.arange(12.0).reshape(6, 2)
+    tok = jnp.array([[0, 5], [2, 2]])
 
-    def fake_impl(f, mesh, in_specs, out_specs, **kw):
-        seen.update(kw)
-        return "mapped"
+    def loss(e):
+        e = jax.lax.with_sharding_constraint(
+            e, NamedSharding(mesh, P("model", None)))
+        return jnp.sum(e[tok] ** 2)
 
-    monkeypatch.setattr(compat, "_SHARD_MAP_IMPL", fake_impl)
-    monkeypatch.setattr(compat, "_CHECK_KW", None)
-    compat.shard_map(lambda x: x, mesh=None, in_specs=(), out_specs=(),
-                     check_vma=True)
-    assert "check_vma" not in seen and "check_rep" not in seen
-
-
-def test_shard_map_explicit_native_kwarg_wins(monkeypatch):
-    """A caller passing the native kwarg directly is not second-guessed."""
-    seen = {}
-
-    def fake_impl(f, mesh, in_specs, out_specs, **kw):
-        seen.update(kw)
-
-    monkeypatch.setattr(compat, "_SHARD_MAP_IMPL", fake_impl)
-    monkeypatch.setattr(compat, "_CHECK_KW", "check_rep")
-    compat.shard_map(lambda x: x, mesh=None, in_specs=(), out_specs=(),
-                     check_vma=True, check_rep=False)
-    assert seen["check_rep"] is False
-
-
-# ---------------------------------------------------------------------------
-# tree_map
-# ---------------------------------------------------------------------------
-
-def test_tree_map_is_usable_and_non_deprecated_path():
-    out = compat.tree_map(lambda a, b: a + b, {"x": 1, "y": (2, 3)},
-                          {"x": 10, "y": (20, 30)})
-    assert out == {"x": 11, "y": (22, 33)}
+    with mesh:
+        g = jax.jit(jax.grad(loss))(emb)
+    want = np.zeros((6, 2))
+    for t in np.asarray(tok).ravel():
+        want[t] += 2 * np.asarray(emb)[t]
+    np.testing.assert_array_equal(np.asarray(g), want)
 
 
 # ---------------------------------------------------------------------------

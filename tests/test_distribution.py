@@ -67,13 +67,14 @@ def test_pjit_train_step_multidevice():
     """Real 2x4 mesh end-to-end train step (8 host devices, subprocess)."""
     out = _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro import compat
         from repro.configs.base import reduced, RunConfig, ShapeConfig
         from repro.configs.registry import get_config
         from repro.models.registry import get_model
         from repro.train import steps as steps_lib
         from repro.optim import adamw
         from repro.data.pipeline import DataConfig, synthetic_batch
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = compat.make_mesh((2, 4), ("data", "model"))
         cfg = reduced(get_config("qwen1.5-4b"), n_layers=2, d_model=64,
                       n_heads=4, n_kv_heads=4, head_dim=16, d_ff=128,
                       vocab_size=256)
@@ -105,6 +106,7 @@ def test_moe_ep_multidevice_matches_single():
     out = _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro import compat
         from repro.configs.base import reduced, RunConfig
         from repro.configs.registry import get_config
         from repro.models import moe as moe_lib
@@ -115,7 +117,7 @@ def test_moe_ep_multidevice_matches_single():
              for k, d in moe_lib.moe_defs(cfg).items()}
         x = jnp.asarray(rng.randn(4, 8, cfg.d_model), jnp.float32)
         ref, aux_ref = moe_lib.moe_apply(x, p, cfg, run, mesh=None)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = compat.make_mesh((2, 4), ("data", "model"))
         with mesh:
             f = jax.jit(lambda x, p: moe_lib.moe_apply(
                 x, p, cfg, run, mesh=mesh, batch_axes=("data",)))
@@ -133,8 +135,9 @@ def test_moe_ep_multidevice_matches_single():
 def test_gpipe_multidevice():
     out = _run_sub("""
         import jax, jax.numpy as jnp, functools
+        from repro import compat
         from repro.distributed.pipeline import gpipe_apply
-        mesh = jax.make_mesh((4,), ("pod",))
+        mesh = compat.make_mesh((4,), ("pod",))
         W = jax.random.normal(jax.random.PRNGKey(0), (4, 16, 16)) * 0.3
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 16))
         fn = lambda w, h: jnp.tanh(h @ w)
@@ -154,6 +157,7 @@ def test_elastic_restore_across_meshes():
     """Save under a 4-device mesh, restore+train under 2 devices."""
     out = _run_sub("""
         import jax, jax.numpy as jnp, tempfile, subprocess, sys, os, textwrap
+        from repro import compat
         from repro.configs.base import reduced, RunConfig, ShapeConfig
         from repro.configs.registry import get_config
         from repro.launch.mesh import make_local_mesh
@@ -162,7 +166,7 @@ def test_elastic_restore_across_meshes():
         cfg = reduced(get_config("qwen1.5-4b"), n_layers=2)
         run = RunConfig(compute_dtype="float32", remat="none", lr=1e-3)
         shape = ShapeConfig("t", "train", 32, 8)
-        mesh = jax.make_mesh((4, 1), ("data", "model"))
+        mesh = compat.make_mesh((4, 1), ("data", "model"))
         tr = Trainer(cfg, run, mesh, shape, ckpt_dir=d, ckpt_every=2)
         with mesh:
             tr.train(2)
@@ -171,13 +175,14 @@ def test_elastic_restore_across_meshes():
     d = out.split("SAVED_DIR")[1].strip()
     out2 = _run_sub(f"""
         import jax
+        from repro import compat
         from repro.configs.base import reduced, RunConfig, ShapeConfig
         from repro.configs.registry import get_config
         from repro.train.trainer import Trainer
         cfg = reduced(get_config("qwen1.5-4b"), n_layers=2)
         run = RunConfig(compute_dtype="float32", remat="none", lr=1e-3)
         shape = ShapeConfig("t", "train", 32, 8)
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        mesh = compat.make_mesh((2, 1), ("data", "model"))
         tr = Trainer(cfg, run, mesh, shape, ckpt_dir={d!r}, ckpt_every=10)
         st = tr.maybe_restore()
         assert st is not None and st.step == 2, st
@@ -194,11 +199,12 @@ def test_perf_knobs_preserve_semantics():
     optimizations: losses and decode logits must match the baseline."""
     out = _run_sub("""
         import jax, jax.numpy as jnp, numpy as np, dataclasses
+        from repro import compat
         from repro.configs.base import reduced, RunConfig, ShapeConfig
         from repro.configs.registry import get_config
         from repro.models.registry import get_model
         from repro.models import lm as lm_lib
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = compat.make_mesh((2, 4), ("data", "model"))
         # 3 heads don't divide model=4 -> pad/reshard paths exercised
         cfg = reduced(get_config("gemma2-2b"), n_layers=2, d_model=48,
                       n_heads=3, n_kv_heads=1, head_dim=16, d_ff=96,

@@ -20,14 +20,10 @@ straight to the earliest witnessed next event. Contract, checked here:
     matches the pre-refactor per-policy golden digests, running THROUGH
     the skipping driver.
 """
-import hashlib
-import json
-from pathlib import Path
-
 import numpy as np
 import pytest
 
-from repro.core import energy, engine, qos
+from repro.core import golden
 from repro.core import policy as policy_api
 from repro.core import simulator as sim
 from repro.core.params import CLS_CPU, CLS_GPU, CLS_HWA, SimConfig
@@ -64,20 +60,6 @@ def _row(pool, active, i):
     return {k: v[i] for k, v in pool.items()}, active[i]
 
 
-def _digest(tree):
-    out = {}
-    for key in sorted(tree):
-        if key.startswith("_"):
-            continue
-        v = np.ascontiguousarray(tree[key])
-        h = hashlib.sha1()
-        h.update(str(v.dtype).encode())
-        h.update(str(v.shape).encode())
-        h.update(v.tobytes())
-        out[key] = h.hexdigest()
-    return out
-
-
 # ---------------------------------------------------------------------------
 # (a) ticked vs skipping bit-identity, every policy, energy + QoS on
 # ---------------------------------------------------------------------------
@@ -111,7 +93,7 @@ def test_final_raw_state_bit_identical(pol):
     ref = sim.simulate_debug(CFG, pol, pool1, act1, N_CYCLES, skip=False)
     got = sim.simulate_debug(CFG, pol, pool1, act1, N_CYCLES, skip=True)
     for part, (r, s) in zip(("src", "sched", "dram"), zip(ref, got)):
-        rd, sd = _digest(r), _digest(s)
+        rd, sd = golden.digest(r), golden.digest(s)
         assert set(sd) == set(rd), f"{pol} {part} keys drifted"
         for k in rd:
             assert sd[k] == rd[k], f"{pol} {part}[{k}] diverged"
@@ -169,49 +151,13 @@ def test_skip_stops_at_hwa_frame_releases():
 # (d) PAR-BS residue fix: stacked slice vs pre-refactor golden, skipping
 # ---------------------------------------------------------------------------
 
-GOLDEN = json.loads(
-    (Path(__file__).parent / "golden_policy_states.json").read_text())
-GCFG = SimConfig(n_cpu=3, n_gpu=1, n_channels=2, buf_entries=24, fifo_size=5,
-                 dcs_size=3)
-
-
-def _golden_pool(cfg):
-    rng = np.random.RandomState(42)
-    S = cfg.n_src
-    mpki = rng.uniform(2, 40, S).astype(np.float32)
-    pool = {
-        "mpki": mpki,
-        "inst_per_miss": np.maximum(1000.0 / mpki, 1.0).astype(np.float32),
-        "rbl": rng.uniform(0.1, 0.95, S).astype(np.float32),
-        "blp": rng.randint(1, 7, S).astype(np.int32),
-        "is_gpu": np.asarray([False] * cfg.n_cpu + [True]),
-        "dl_period": np.zeros(S, np.int32),
-        "dl_reqs": np.zeros(S, np.int32),
-    }
-    pool["dl_period"][0] = 400
-    pool["dl_reqs"][0] = 35
-    return pool
-
-
 def test_parbs_stacked_slice_matches_golden_through_skip_driver():
     """The amortized-rank reformulation (no per-cycle sort, no batched
     cond residue) + the skipping driver, against the digests captured
     before either existed: the batch machinery is bit-preserved."""
-    fam = sim.stackable_names(GCFG)
-    out = sim.simulate_debug_stacked(GCFG, fam, _golden_pool(GCFG),
-                                     np.ones(GCFG.n_src, bool),
-                                     n_cycles=1_500, skip=True)
-    st_f, sched_f, dram_f = out["parbs"]
-    g = GOLDEN["parbs"]
-    for part, tree in (("src", st_f), ("dram", dram_f)):
-        new = _digest(tree)
-        allowed = set(energy.STATE_KEYS) | set(qos.STATE_KEYS) \
-            if part == "dram" else set(engine.NCLASS_SRC_KEYS)
-        assert set(new) ^ set(g[part]) <= allowed
-        for k, h in g[part].items():
-            assert new[k] == h, f"parbs {part}[{k}] diverged"
-    sched = _digest(sched_f)
-    shared = set(sched) & set(g["sched"])
-    assert {"valid", "src", "bank", "row", "birth", "marked"} <= shared
-    for k in shared:
-        assert sched[k] == g["sched"][k], f"parbs sched[{k}] diverged"
+    fam = sim.stackable_names(golden.CFG)
+    out = sim.simulate_debug_stacked(golden.CFG, fam, golden.pool(),
+                                     np.ones(golden.CFG.n_src, bool),
+                                     n_cycles=golden.N_CYCLES, skip=True)
+    bad = golden.compare("parbs", out["parbs"], golden.load()["parbs"])
+    assert not bad, bad

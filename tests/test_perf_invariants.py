@@ -15,7 +15,6 @@ the trace:
   * the refactor is bit-identical: the golden digests for atlas/parbs/tcm
     (captured pre-refactor) still match.
 """
-import hashlib
 import json
 from pathlib import Path
 
@@ -25,7 +24,7 @@ import numpy as np
 import pytest
 
 from repro import compat
-from repro.core import energy, engine, params, qos, validate
+from repro.core import golden, params, validate
 from repro.core import policy as policy_api
 from repro.core import simulator as sim
 from repro.core.params import Knobs, SimConfig
@@ -251,40 +250,7 @@ def test_scan_carry_has_no_pool_or_active():
 # test_policy_registry, focused on the three re-ranked policies)
 # ---------------------------------------------------------------------------
 
-GOLDEN = json.loads(
-    (Path(__file__).parent / "golden_policy_states.json").read_text())
-
-
-def _golden_pool(cfg):
-    rng = np.random.RandomState(42)
-    S = cfg.n_src
-    mpki = rng.uniform(2, 40, S).astype(np.float32)
-    pool = {
-        "mpki": mpki,
-        "inst_per_miss": np.maximum(1000.0 / mpki, 1.0).astype(np.float32),
-        "rbl": rng.uniform(0.1, 0.95, S).astype(np.float32),
-        "blp": rng.randint(1, 7, S).astype(np.int32),
-        "is_gpu": np.asarray([False] * cfg.n_cpu + [True]),
-        "dl_period": np.zeros(S, np.int32),
-        "dl_reqs": np.zeros(S, np.int32),
-    }
-    pool["dl_period"][0] = 400
-    pool["dl_reqs"][0] = 35
-    return pool
-
-
-def _digest(tree):
-    out = {}
-    for key in sorted(tree):
-        if key.startswith("_"):
-            continue
-        v = np.ascontiguousarray(tree[key])
-        h = hashlib.sha1()
-        h.update(str(v.dtype).encode())
-        h.update(str(v.shape).encode())
-        h.update(v.tobytes())
-        out[key] = h.hexdigest()
-    return out
+GOLDEN = golden.load()
 
 
 @pytest.mark.parametrize("policy_name", ["atlas", "parbs", "tcm"])
@@ -292,22 +258,11 @@ def test_cond_refactor_bit_identical(policy_name):
     # runs with the energy subsystem ON (CFG default): the goldens predate
     # it, so matching them on every non-energy key proves energy accounting
     # is purely additive to the scheduling decisions
-    st_f, sched_f, dram_f = sim.simulate_debug(
-        CFG, policy_name, _golden_pool(CFG), np.ones(CFG.n_src, bool),
-        n_cycles=1_500)
-    g = GOLDEN[policy_name]
-    for part, tree in (("src", st_f), ("dram", dram_f)):
-        new = _digest(tree)
-        extra = set(new) - set(g[part])
-        allowed = set(energy.STATE_KEYS) | set(qos.STATE_KEYS) \
-            if part == "dram" else set(engine.NCLASS_SRC_KEYS)
-        assert extra <= allowed, \
-            f"{policy_name} {part} grew unexpected keys: {extra}"
-        for k, h in g[part].items():
-            assert new[k] == h, f"{policy_name} {part}[{k}] diverged"
-    sched = _digest(sched_f)
-    for k in set(sched) & set(g["sched"]):
-        assert sched[k] == g["sched"][k], f"{policy_name} sched[{k}] diverged"
+    state = sim.simulate_debug(
+        CFG, policy_name, golden.pool(CFG), np.ones(CFG.n_src, bool),
+        n_cycles=golden.N_CYCLES)
+    bad = golden.compare(policy_name, state, GOLDEN[policy_name])
+    assert not bad, bad
 
 
 @pytest.mark.parametrize("policy_name", ["atlas", "parbs", "tcm"])
@@ -316,24 +271,11 @@ def test_validate_on_bit_identical(policy_name):
     still matches bit-for-bit (the counters never feed back into a
     scheduling decision), the only new dram key is the violation vector,
     and that vector is all zeros on a healthy run."""
-    st_f, sched_f, dram_f = sim.simulate_debug(
-        CFG.replace(validate_enabled=True), policy_name, _golden_pool(CFG),
-        np.ones(CFG.n_src, bool), n_cycles=1_500)
-    assert not np.asarray(dram_f["viol"]).any(), \
-        validate.summarize(np.asarray(dram_f["viol"]))
-    g = GOLDEN[policy_name]
-    for part, tree in (("src", st_f), ("dram", dram_f)):
-        new = _digest(tree)
-        extra = set(new) - set(g[part])
-        allowed = set(energy.STATE_KEYS) | set(qos.STATE_KEYS) \
-            | set(validate.STATE_KEYS) if part == "dram" \
-            else set(engine.NCLASS_SRC_KEYS)
-        assert extra <= allowed, \
-            f"{policy_name} {part} grew unexpected keys: {extra}"
-        for k, h in g[part].items():
-            assert new[k] == h, \
-                f"{policy_name} {part}[{k}] diverged under validate"
-    sched = _digest(sched_f)
-    for k in set(sched) & set(g["sched"]):
-        assert sched[k] == g["sched"][k], \
-            f"{policy_name} sched[{k}] diverged under validate"
+    state = sim.simulate_debug(
+        CFG.replace(validate_enabled=True), policy_name, golden.pool(CFG),
+        np.ones(CFG.n_src, bool), n_cycles=golden.N_CYCLES)
+    viol = np.asarray(state[2]["viol"])
+    assert not viol.any(), validate.summarize(viol)
+    bad = golden.compare(policy_name, state, GOLDEN[policy_name],
+                         extra_dram=validate.STATE_KEYS)
+    assert not bad, f"diverged under validate: {bad}"

@@ -7,64 +7,19 @@ bit-identical: src and dram state must match key-for-key in both
 directions; scheduler state must match on every key that survived the port
 (per-policy state was slimmed — e.g. frfcfs no longer carries ATLAS's
 `attained` — so legacy-only keys are allowed to disappear, but shared keys
-may not drift).
+may not drift). The comparison itself is `repro.core.golden.compare`.
 """
-import json
-import hashlib
-from pathlib import Path
-
 import numpy as np
 import pytest
 
-from repro.core import energy, engine, policy, qos
+from repro.core import energy, golden, policy
 from repro.core import simulator as sim
 from repro.core.params import SimConfig
 from repro.serving.scheduler import SCHEDULERS as SERVING_SCHEDULERS
 
-GOLDEN = json.loads(
-    (Path(__file__).parent / "golden_policy_states.json").read_text())
-
-CFG = SimConfig(n_cpu=3, n_gpu=1, n_channels=2, buf_entries=24, fifo_size=5,
-                dcs_size=3)
-N_CYCLES = 1_500
-# keys whose presence proves the sched comparison isn't vacuous
-ESSENTIAL_SCHED = {
-    "sms": ("f_len", "f_row", "d_len", "d_src", "drain_left", "rr_bank"),
-    "centralized": ("valid", "src", "bank", "row", "birth", "marked"),
-}
-
-
-def _golden_pool(cfg):
-    """Must match the capture-time generator exactly (seed 42)."""
-    rng = np.random.RandomState(42)
-    S = cfg.n_src
-    mpki = rng.uniform(2, 40, S).astype(np.float32)
-    pool = {
-        "mpki": mpki,
-        "inst_per_miss": np.maximum(1000.0 / mpki, 1.0).astype(np.float32),
-        "rbl": rng.uniform(0.1, 0.95, S).astype(np.float32),
-        "blp": rng.randint(1, 7, S).astype(np.int32),
-        "is_gpu": np.asarray([False] * cfg.n_cpu + [True]),
-        "dl_period": np.zeros(S, np.int32),
-        "dl_reqs": np.zeros(S, np.int32),
-    }
-    pool["dl_period"][0] = 400
-    pool["dl_reqs"][0] = 35
-    return pool
-
-
-def _digest(tree):
-    out = {}
-    for key in sorted(tree):
-        if key.startswith("_"):
-            continue
-        v = np.ascontiguousarray(tree[key])
-        h = hashlib.sha1()
-        h.update(str(v.dtype).encode())
-        h.update(str(v.shape).encode())
-        h.update(v.tobytes())
-        out[key] = h.hexdigest()
-    return out
+GOLDEN = golden.load()
+CFG = golden.CFG
+N_CYCLES = golden.N_CYCLES
 
 
 @pytest.mark.parametrize("policy_name", sorted(GOLDEN))
@@ -74,29 +29,13 @@ def test_ported_policy_bit_identical(policy_name):
     # key must still match bit-for-bit, and the only new dram keys allowed
     # are the energy counters themselves
     assert CFG.energy_enabled, "additivity check must run with energy on"
-    st_f, sched_f, dram_f = sim.simulate_debug(
-        CFG, policy_name, _golden_pool(CFG), np.ones(CFG.n_src, bool),
+    state = sim.simulate_debug(
+        CFG, policy_name, golden.pool(CFG), np.ones(CFG.n_src, bool),
         n_cycles=N_CYCLES)
-    g = GOLDEN[policy_name]
-    for part, tree in (("src", st_f), ("dram", dram_f)):
-        new = _digest(tree)
-        # additive-only subsystems may add keys on top of the goldens:
-        # energy + QoS counters (dram), N-class frame accounting (src)
-        allowed = set(energy.STATE_KEYS) | set(qos.STATE_KEYS) \
-            if part == "dram" else set(engine.NCLASS_SRC_KEYS)
-        assert set(new) ^ set(g[part]) <= allowed, \
-            f"{policy_name} {part} keys drifted: {set(new) ^ set(g[part])}"
-        for k, h in g[part].items():
-            assert new[k] == h, f"{policy_name} {part}[{k}] diverged"
-    assert set(energy.STATE_KEYS) <= set(dram_f), \
+    assert set(energy.STATE_KEYS) <= set(state[2]), \
         "energy counters missing — the additivity check would be vacuous"
-    sched = _digest(sched_f)
-    essential = ESSENTIAL_SCHED[
-        "sms" if policy_name.startswith("sms") else "centralized"]
-    for k in essential:
-        assert k in sched and k in g["sched"], f"missing sched key {k}"
-    for k in set(sched) & set(g["sched"]):
-        assert sched[k] == g["sched"][k], f"{policy_name} sched[{k}] diverged"
+    bad = golden.compare(policy_name, state, GOLDEN[policy_name])
+    assert not bad, bad
 
 
 # ---------------------------------------------------------------------------

@@ -5,6 +5,7 @@ an MC structure, pending at the core}. Structural bounds: FIFO lengths within
 capacity, non-negative stats. Physical bounds: data-bus occupancy can never
 exceed 1 burst per t_burst cycles per channel.
 """
+import jax
 import numpy as np
 import pytest
 
@@ -128,6 +129,27 @@ def test_inactive_sources_stay_silent():
     st_f, _, _ = sim.simulate_debug(CFG, "sms", pool, active, 2_000)
     assert st_f["emitted"][1:].sum() == 0
     assert st_f["emitted"][0] > 0
+
+
+@pytest.mark.parametrize("kind", ["counts", "wide", "per_cycle"])
+def test_div_rn_is_ieee_division(kind):
+    """`div_rn` (the metric ratios) rounds exactly as IEEE f32 division."""
+    rng = np.random.default_rng(7)
+    n = 100_000
+    if kind == "counts":
+        a = rng.integers(0, 2**31 - 128, n).astype(np.float32)
+        b = rng.integers(1, 2**20, n).astype(np.float32)
+    elif kind == "wide":
+        a = np.exp(rng.uniform(-40, 40, n)).astype(np.float32)
+        b = np.exp(rng.uniform(-40, 40, n)).astype(np.float32)
+    else:
+        a = rng.uniform(0, 1e6, n).astype(np.float32)
+        b = np.full(n, 16_000, np.float32)
+    a[:3] = [0.0, 1.0, 3.0]
+    got = np.asarray(jax.jit(sim.div_rn)(a, b))
+    want = a / b
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
 
 
 def test_rbl_measured_tracks_generator():

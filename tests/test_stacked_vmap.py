@@ -17,63 +17,23 @@ policy axis. Contract, checked here:
   * stackability is an explicit opt-in: SMS-style protocols and configured
     variants (sms_dash) stay on the per-policy path.
 """
-import hashlib
-import json
-from pathlib import Path
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import compat
-from repro.core import energy, engine, qos
+from repro.core import golden
 from repro.core import policy as policy_api
 from repro.core import schedulers
 from repro.core import simulator as sim
-from repro.core.params import SimConfig
 
-CFG = SimConfig(n_cpu=3, n_gpu=1, n_channels=2, buf_entries=24, fifo_size=5,
-                dcs_size=3)
+CFG = golden.CFG
 SORT_PRIMS = {"sort"}
 
-GOLDEN = json.loads(
-    (Path(__file__).parent / "golden_policy_states.json").read_text())
+GOLDEN = golden.load()
 
 FAMILY = sim.stackable_names(CFG)
-
-
-def _golden_pool(cfg):
-    """Must match the capture-time generator exactly (seed 42)."""
-    rng = np.random.RandomState(42)
-    S = cfg.n_src
-    mpki = rng.uniform(2, 40, S).astype(np.float32)
-    pool = {
-        "mpki": mpki,
-        "inst_per_miss": np.maximum(1000.0 / mpki, 1.0).astype(np.float32),
-        "rbl": rng.uniform(0.1, 0.95, S).astype(np.float32),
-        "blp": rng.randint(1, 7, S).astype(np.int32),
-        "is_gpu": np.asarray([False] * cfg.n_cpu + [True]),
-        "dl_period": np.zeros(S, np.int32),
-        "dl_reqs": np.zeros(S, np.int32),
-    }
-    pool["dl_period"][0] = 400
-    pool["dl_reqs"][0] = 35
-    return pool
-
-
-def _digest(tree):
-    out = {}
-    for key in sorted(tree):
-        if key.startswith("_"):
-            continue
-        v = np.ascontiguousarray(tree[key])
-        h = hashlib.sha1()
-        h.update(str(v.dtype).encode())
-        h.update(str(v.shape).encode())
-        h.update(v.tobytes())
-        out[key] = h.hexdigest()
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -84,32 +44,17 @@ def _digest(tree):
 def stacked_final_states():
     """One stacked run of the whole family at the golden config."""
     return sim.simulate_debug_stacked(
-        CFG, FAMILY, _golden_pool(CFG), np.ones(CFG.n_src, bool),
-        n_cycles=1_500)
+        CFG, FAMILY, golden.pool(CFG), np.ones(CFG.n_src, bool),
+        n_cycles=golden.N_CYCLES)
 
 
 @pytest.mark.parametrize("policy_name",
                          [n for n in FAMILY if n in GOLDEN])
 def test_stacked_slice_bit_identical_to_golden(policy_name,
                                                stacked_final_states):
-    st_f, sched_f, dram_f = stacked_final_states[policy_name]
-    g = GOLDEN[policy_name]
-    for part, tree in (("src", st_f), ("dram", dram_f)):
-        new = _digest(tree)
-        # energy/QoS counters and the N-class frame accounting are
-        # additive-only extras on the stacked path too: every pre-existing
-        # golden key must still match bit-for-bit
-        allowed = set(energy.STATE_KEYS) | set(qos.STATE_KEYS) \
-            if part == "dram" else set(engine.NCLASS_SRC_KEYS)
-        assert set(new) ^ set(g[part]) <= allowed, \
-            f"{policy_name} {part} keys drifted: {set(new) ^ set(g[part])}"
-        for k, h in g[part].items():
-            assert new[k] == h, f"{policy_name} {part}[{k}] diverged"
-    sched = _digest(sched_f)
-    shared = set(sched) & set(g["sched"])
-    assert {"valid", "src", "bank", "row", "birth", "marked"} <= shared
-    for k in shared:
-        assert sched[k] == g["sched"][k], f"{policy_name} sched[{k}] diverged"
+    bad = golden.compare(policy_name, stacked_final_states[policy_name],
+                         GOLDEN[policy_name])
+    assert not bad, bad
 
 
 @pytest.mark.parametrize("policy_name",
@@ -118,11 +63,12 @@ def test_stacked_slice_bit_identical_to_debug(policy_name,
                                               stacked_final_states):
     """Policies younger than the golden capture (bliss, squash_prio):
     compare the stacked slice against a fresh standalone run instead."""
-    ref = sim.simulate_debug(CFG, policy_name, _golden_pool(CFG),
-                             np.ones(CFG.n_src, bool), n_cycles=1_500)
+    ref = sim.simulate_debug(CFG, policy_name, golden.pool(CFG),
+                             np.ones(CFG.n_src, bool),
+                             n_cycles=golden.N_CYCLES)
     got = stacked_final_states[policy_name]
     for part, (r, s) in zip(("src", "sched", "dram"), zip(ref, got)):
-        rd, sd = _digest(r), _digest(s)
+        rd, sd = golden.digest(r), golden.digest(s)
         assert set(sd) == set(rd), f"{policy_name} {part} keys drifted"
         for k in rd:
             assert sd[k] == rd[k], f"{policy_name} {part}[{k}] diverged"

@@ -19,7 +19,6 @@ Five layers:
   * the host-side views (`metrics.timeline_breakdown`) and the perf-trend
     ledger (`benchmarks.bench_trend`) hold their accounting identities.
 """
-import hashlib
 import json
 
 import jax
@@ -27,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import engine, telemetry
+from repro.core import engine, golden, telemetry
 from repro.core import metrics as met
 from repro.core import policy as policy_api
 from repro.core import simulator as sim
@@ -68,20 +67,6 @@ def _mix_pool():
 
 def _row(pool, active, i):
     return {k: v[i] for k, v in pool.items()}, active[i]
-
-
-def _digest(tree):
-    out = {}
-    for key in sorted(tree):
-        if key.startswith("_"):
-            continue
-        v = np.ascontiguousarray(tree[key])
-        h = hashlib.sha1()
-        h.update(str(v.dtype).encode())
-        h.update(str(v.shape).encode())
-        h.update(v.tobytes())
-        out[key] = h.hexdigest()
-    return out
 
 
 def _stackable(cfg):
@@ -143,7 +128,7 @@ def test_on_is_measurement_only(pol):
     ref = sim.simulate_debug(BASE, pol, pool1, act1, N_CYCLES, skip=True)
     got = sim.simulate_debug(CFG, pol, pool1, act1, N_CYCLES, skip=True)
     for part, (r, g) in zip(("src", "sched", "dram"), zip(ref, got)):
-        rd, gd = _digest(r), _digest(g)
+        rd, gd = golden.digest(r), golden.digest(g)
         assert set(gd) - set(rd) <= set(telemetry.STATE_KEYS), \
             f"{pol} {part} grew unexpected keys: {set(gd) - set(rd)}"
         for k in rd:
