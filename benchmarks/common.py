@@ -16,6 +16,8 @@ rows on stdout.
 """
 from __future__ import annotations
 
+import atexit
+import collections
 import contextlib
 import hashlib
 import json
@@ -25,8 +27,9 @@ import sys
 import tempfile
 import time
 from pathlib import Path
-from typing import Dict, List, Optional, Sequence
+from typing import Deque, Dict, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro.core import metrics as met
@@ -47,7 +50,7 @@ TRACE_DIR = Path(__file__).resolve().parents[1] / "experiments" / "trace"
 CACHE_VERSION = "pr10-telemetry"
 
 # ---------------------------------------------------------------------------
-# diagnostics: a leveled logger (REPRO_LOG_LEVEL) + a structured JSONL trace
+# diagnostics: a leveled logger (REPRO_LOG_LEVEL) + structured sweep spans
 # (REPRO_TRACE) replace the old raw [sweep-recover] prints. Both write to
 # stderr/files only — the CSV contract on stdout stays machine-parsable.
 # ---------------------------------------------------------------------------
@@ -66,8 +69,8 @@ _TRACE_FILE: Optional[Path] = None
 def trace_path() -> Optional[Path]:
     """This process's JSONL trace file (None when REPRO_TRACE=0).
 
-    One file per process under experiments/trace/, opened lazily on the
-    first event so importing the harness never touches the filesystem.
+    One file per process under experiments/trace/, named at the first
+    record so importing the harness never touches the filesystem.
     """
     global _TRACE_FILE
     if os.environ.get("REPRO_TRACE", "1") == "0":
@@ -79,42 +82,82 @@ def trace_path() -> Optional[Path]:
     return _TRACE_FILE
 
 
+class SpanLog:
+    """The sweeps' spans and events, kept in memory and written to the JSONL
+    trace (`trace_path`) when a root span closes and at exit.
+
+    A record is {"ts", "event", "id", "parent", "sweep_id", **fields}: `ts`
+    is the wall-clock start, `parent` the id of the innermost open span,
+    `sweep_id` the id of the root span it lies under (a `run_sweep` call's
+    `sweep`); a span adds its `dur_s`. A span is also a
+    `jax.profiler.TraceAnnotation` of the same name, so in a profiler
+    session it lands on the trace's host plane, on the device's clock.
+    `records` keeps the latest `KEEP` records for readers in the process,
+    whether or not the file is written.
+    """
+
+    KEEP = 4096
+
+    def __init__(self):
+        self.records: Deque[Dict] = collections.deque(maxlen=self.KEEP)
+        self._open: List[Dict] = []
+        self._unwritten: List[Dict] = []
+        self._next_id = 0
+
+    def event(self, event: str, **fields) -> Dict:
+        parent = self._open[-1] if self._open else None
+        rec = {"ts": round(time.time(), 6), "event": event,
+               "id": self._next_id,
+               "parent": parent["id"] if parent else None,
+               "sweep_id": parent["sweep_id"] if parent else None,
+               **fields}
+        self._next_id += 1
+        self.records.append(rec)
+        if trace_path() is not None:
+            self._unwritten.append(rec)
+        return rec
+
+    @contextlib.contextmanager
+    def span(self, event: str, **fields):
+        """Yields the span's record, to which the body may add fields."""
+        rec = self.event(event, **fields)
+        if rec["sweep_id"] is None:
+            rec["sweep_id"] = rec["id"]
+        self._open.append(rec)
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(event):
+                yield rec
+        finally:
+            rec["dur_s"] = round(time.perf_counter() - t0, 6)
+            self._open.pop()
+            if not self._open:
+                self.flush()
+
+    def flush(self) -> None:
+        path = trace_path()
+        if path is None or not self._unwritten:
+            return
+        try:
+            with path.open("a") as f:
+                f.writelines(json.dumps(r) + "\n" for r in self._unwritten)
+        except OSError as e:                 # tracing must never kill a sweep
+            LOG.debug("trace write failed: %r", e)
+        self._unwritten.clear()
+
+
+SPANS = SpanLog()
+atexit.register(SPANS.flush)
+
+
 def trace_event(event: str, **fields) -> None:
-    """Append one structured event ({"ts", "event", ...}) to the trace."""
-    path = trace_path()
-    if path is None:
-        return
-    rec = {"ts": round(time.time(), 6), "event": event, **fields}
-    try:
-        with path.open("a") as f:
-            f.write(json.dumps(rec) + "\n")
-    except OSError as e:                     # tracing must never kill a sweep
-        LOG.debug("trace write failed: %r", e)
+    """Record one point event under the innermost open span."""
+    SPANS.event(event, **fields)
 
 
-@contextlib.contextmanager
 def trace_span(event: str, **fields):
-    """Span event: one record at exit with the measured `dur_s`."""
-    t0 = time.time()
-    try:
-        yield
-    finally:
-        trace_event(event, dur_s=round(time.time() - t0, 6), **fields)
-
-
-@contextlib.contextmanager
-def _maybe_profile(label: str):
-    """Opt-in `jax.profiler` capture around a dispatch: set
-    REPRO_PROFILE_DIR to a directory to record a TensorBoard-loadable
-    trace of the stacked program (off by default — profiling is not
-    free)."""
-    pdir = os.environ.get("REPRO_PROFILE_DIR")
-    if not pdir:
-        yield
-        return
-    import jax
-    with jax.profiler.trace(os.path.join(pdir, label)):
-        yield
+    """Span `event` (`SpanLog.span`) of this process's `SPANS`."""
+    return SPANS.span(event, **fields)
 
 
 def _log_backoff(msg: str) -> None:
@@ -337,12 +380,26 @@ def run_sweep(cfg: SimConfig, policies: Sequence[str],
     dict as ``{"policy": ..., "error": ...}`` (never cached, so a re-run
     retries it) while every healthy slice is persisted per-slice as it
     completes. `strict=True` re-raises at the first failure instead.
+
+    Spans (`trace_span`): `sweep` holds the call, and under it
+    `sweep.pools` (the alone and mix pools), one `sweep.dispatch` per
+    program, and per policy `sweep.fetch` (wait for the device and copy
+    back) and `sweep.rows` (per-row metrics, aggregates, the cache write).
     """
-    trace_event("sweep_begin", tag=tag or "std", policies=list(policies),
-                n_workloads=len(workloads), n_cycles=n_cycles)
-    apool, aactive, amap = wl.alone_batch(cfg)
+    with trace_span("sweep", tag=tag or "std", policies=list(policies),
+                    n_workloads=len(workloads), n_cycles=n_cycles) as span:
+        results = _run_sweep(cfg, policies, workloads, n_cycles, warmup,
+                             seed, tag, force, stacked, strict)
+        span["errors"] = [p for p, r in results.items() if "error" in r]
+    return results
+
+
+def _run_sweep(cfg, policies, workloads, n_cycles, warmup, seed, tag, force,
+               stacked, strict) -> Dict[str, Dict]:
+    with trace_span("sweep.pools"):
+        apool, aactive, amap = wl.alone_batch(cfg)
+        pool, active = wl.pool_batch(cfg, workloads)
     n_alone = len(amap)
-    pool, active = wl.pool_batch(cfg, workloads)
     results: Dict[str, Dict] = {}
     todo = []
     for pol in policies:
@@ -385,7 +442,7 @@ def run_sweep(cfg: SimConfig, policies: Sequence[str],
         bp, ba = batch_for(alone is None)
         try:
             # the async-dispatch span covers trace + compile + enqueue
-            with trace_span("compile_dispatch", policy=pol, stacked=False):
+            with trace_span("sweep.dispatch", policy=pol, stacked=False):
                 dev = sim.simulate_async(cfg, pol, bp, ba, n_cycles, warmup)
             fetch = lambda dev=dev: {k: np.asarray(v)
                                      for k, v in dev.items()}
@@ -406,8 +463,8 @@ def run_sweep(cfg: SimConfig, policies: Sequence[str],
         bp, ba = batch_for(need_alone)
         try:
             names = [p for p, _, _ in items]
-            with trace_span("compile_dispatch", policies=names,
-                            stacked=True), _maybe_profile("stacked_sweep"):
+            with trace_span("sweep.dispatch", policies=names,
+                            stacked=True):
                 dev = sim.simulate_stacked_async(
                     cfg, tuple(names), bp, ba, n_cycles, warmup)
         except Exception as e:
@@ -435,7 +492,7 @@ def run_sweep(cfg: SimConfig, policies: Sequence[str],
         # by benchmarks/simspeed.py as sweep wall-clock
         t0 = time.time()
         try:
-            with trace_span("fetch", policy=pol):
+            with trace_span("sweep.fetch", policy=pol):
                 m = _fetch_recover(cfg, pol, pol, None, fetch, bp, ba,
                                    n_cycles, warmup, strict)
         except Exception as e:
@@ -445,38 +502,37 @@ def run_sweep(cfg: SimConfig, policies: Sequence[str],
                          f"recording error entry (not cached)")
             results[pol] = {"policy": pol, "error": repr(e)}
             continue
-        if alone is None:
-            am = {k: v[:n_alone] for k, v in m.items()}
-            m = {k: v[n_alone:] for k, v in m.items()}
-            alone = wl.alone_perf_lookup(cfg, am, amap)
-            _save_alone(cfg, pol, n_cycles, warmup, alone)
-            trace_event("alone_baseline", policy=pol, n_rows=n_alone)
-        perf = sim.perf_vector(cfg, m, pool)
-        rows = [met.workload_metrics(cfg, w, perf[i], alone)
-                for i, w in enumerate(workloads)]
-        if "lat_hist" in m:
-            # per-class QoS columns (tail latency, deadline-met rate) join
-            # the speedup/fairness rows, so agg/by_category cover them too
-            qb = met.qos_breakdown(cfg, m, pool)
-            for i, r in enumerate(rows):
-                r.update({k: float(v[i]) for k, v in qb.items()})
-        out = {
-            "policy": pol,
-            "cache_version": CACHE_VERSION,
-            "elapsed_s": round(time.time() - t0, 1),
-            "alone": alone,
-            "rows": rows,
-            "categories": [w.category for w in workloads],
-            "agg": met.aggregate(rows),
-            "by_category": met.by_category(workloads, rows),
-            "measured": {k: np.asarray(v).mean(0).tolist()
-                         for k, v in m.items()},
-        }
-        path.parent.mkdir(parents=True, exist_ok=True)
-        path.write_text(json.dumps(out, indent=1))
+        with trace_span("sweep.rows", policy=pol):
+            if alone is None:
+                am = {k: v[:n_alone] for k, v in m.items()}
+                m = {k: v[n_alone:] for k, v in m.items()}
+                alone = wl.alone_perf_lookup(cfg, am, amap)
+                _save_alone(cfg, pol, n_cycles, warmup, alone)
+                trace_event("alone_baseline", policy=pol, n_rows=n_alone)
+            perf = sim.perf_vector(cfg, m, pool)
+            rows = [met.workload_metrics(cfg, w, perf[i], alone)
+                    for i, w in enumerate(workloads)]
+            if "lat_hist" in m:
+                # per-class QoS columns (tail latency, deadline-met rate) join
+                # the speedup/fairness rows, so agg/by_category cover them too
+                qb = met.qos_breakdown(cfg, m, pool)
+                for i, r in enumerate(rows):
+                    r.update({k: float(v[i]) for k, v in qb.items()})
+            out = {
+                "policy": pol,
+                "cache_version": CACHE_VERSION,
+                "elapsed_s": round(time.time() - t0, 1),
+                "alone": alone,
+                "rows": rows,
+                "categories": [w.category for w in workloads],
+                "agg": met.aggregate(rows),
+                "by_category": met.by_category(workloads, rows),
+                "measured": {k: np.asarray(v).mean(0).tolist()
+                             for k, v in m.items()},
+            }
+            path.parent.mkdir(parents=True, exist_ok=True)
+            path.write_text(json.dumps(out, indent=1))
         results[pol] = out
-    trace_event("sweep_end", tag=tag or "std",
-                errors=[p for p, r in results.items() if "error" in r])
     return {pol: results[pol] for pol in policies}
 
 
@@ -557,9 +613,8 @@ def run_grid(cfg: SimConfig, specs: Sequence, workloads: Sequence[wl.Workload],
             singles.append(items[0])
             return
         try:
-            with trace_span("compile_dispatch", stacked=True, grid=True,
-                            labels=[it[1] for it in items]), \
-                    _maybe_profile("stacked_grid"):
+            with trace_span("sweep.dispatch", stacked=True, grid=True,
+                            labels=[it[1] for it in items]):
                 dev = sim.simulate_stacked_grid_async(
                     cfg, [(p, ov) for p, _, ov, _ in items],
                     batch_pool, batch_active, n_cycles, warmup)
@@ -590,7 +645,7 @@ def run_grid(cfg: SimConfig, specs: Sequence, workloads: Sequence[wl.Workload],
         gcfg = cfg.replace(**dict(per))
         points = [params.split_overrides(it[2])[1] for it in items]
         try:
-            with trace_span("compile_dispatch", policy=polname, grid=True,
+            with trace_span("sweep.dispatch", policy=polname, grid=True,
                             labels=[it[1] for it in items]):
                 dev = sim.simulate_grid_async(gcfg, polname, points,
                                               batch_pool, batch_active,
@@ -610,7 +665,7 @@ def run_grid(cfg: SimConfig, specs: Sequence, workloads: Sequence[wl.Workload],
         t0 = time.time()
         per, point = params.split_overrides(ov)
         try:
-            with trace_span("fetch", policy=polname, label=label):
+            with trace_span("sweep.fetch", policy=polname, label=label):
                 m = _fetch_recover(cfg.replace(**per), polname, label,
                                    point, fetch, batch_pool, batch_active,
                                    n_cycles, warmup, strict)

@@ -9,6 +9,7 @@ on plain "sms" without touching the registry at all.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import policy, sms as sms_lib
@@ -28,13 +29,17 @@ class SMS:
     def init_state(self, cfg):
         return sms_lib.sms_state(cfg)
 
+    # each stage under its own scope (`policy.STEP_SCOPES`)
     def tick(self, cfg, pool, st, sched, t):
-        st, sched = sms_lib.stage1_admit(cfg, st, sched, t)
-        st, sched = sms_lib.stage2_drain(cfg, pool, st, sched, t)
+        with jax.named_scope("sms.stage1"):
+            st, sched = sms_lib.stage1_admit(cfg, st, sched, t)
+        with jax.named_scope("sms.stage2"):
+            st, sched = sms_lib.stage2_drain(cfg, pool, st, sched, t)
         return st, sched
 
     def select(self, cfg, pool, st, sched, dram, t):
-        return sms_lib.stage3_issue(cfg, st, sched, dram, t)
+        with jax.named_scope("sms.stage3"):
+            return sms_lib.stage3_issue(cfg, st, sched, dram, t)
 
     # -- variable-step driver witness (see `policy.make_skip_step`) ---------
     def next_event(self, cfg, pool, st, sched, dram, t):

@@ -40,6 +40,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, Dict, Iterator, Optional, Protocol, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -183,6 +184,23 @@ def is_stackable(name: str, cfg: SimConfig) -> bool:
                for f in params.KNOB_FIELDS)
 
 
+# The stages of the cycle step, as `jax.named_scope`s. A scope is trace-time
+# metadata: it adds no primitive, and reaches every compiled op's `op_name`
+# (`.../while/body/step.select/pol.select/sms.stage3/...`), so a device
+# trace can be read per stage. The `step.*` scopes are disjoint at the top of
+# a cycle; the others nest inside them. `step.telemetry`, `step.validate`
+# and `step.skip` are traced only where telemetry, the sanitizer or the
+# skipping driver is on. `make_stacked_step` uses `select.*` where the
+# per-policy step has `pol.*`; the SMS stages add `sms.*`.
+STEP_SCOPES = (
+    "step.engine", "step.admit", "step.select", "step.telemetry",
+    "step.validate", "step.skip",
+    "pol.tick", "pol.select",
+    "select.eligibility", "select.score", "select.issue", "select.clear",
+    "sms.stage1", "sms.stage2", "sms.stage3",
+)
+
+
 def make_step(cfg: SimConfig, pol: MemoryPolicy, pool, active):
     """One simulator cycle, generic over the policy object.
 
@@ -195,20 +213,27 @@ def make_step(cfg: SimConfig, pol: MemoryPolicy, pool, active):
     def step(carry, t):
         st, sched, dram = carry
         if cfg.telemetry_enabled:
-            snap = telemetry.snapshot(st, sched, dram)
-        st, dram = engine.completions_tick(st, dram, t)
-        dram = energy.background_tick(cfg, dram, t)
-        st = engine.deadline_tick(cfg, pool, st, t)
-        st = engine.source_tick(cfg, pool, st, active, t)
-        st, sched = pol.tick(cfg, pool, st, sched, t)
-        st, sched, dram = pol.select(cfg, pool, st, sched, dram, t)
+            with jax.named_scope("step.telemetry"):
+                snap = telemetry.snapshot(st, sched, dram)
+        with jax.named_scope("step.engine"):
+            st, dram = engine.completions_tick(st, dram, t)
+            dram = energy.background_tick(cfg, dram, t)
+            st = engine.deadline_tick(cfg, pool, st, t)
+            st = engine.source_tick(cfg, pool, st, active, t)
+        with jax.named_scope("step.admit"), jax.named_scope("pol.tick"):
+            st, sched = pol.tick(cfg, pool, st, sched, t)
+        with jax.named_scope("step.select"), jax.named_scope("pol.select"):
+            st, sched, dram = pol.select(cfg, pool, st, sched, dram, t)
         if cfg.telemetry_enabled:
-            dram = telemetry.tick_accrue(cfg, pool, snap, st, sched, dram, t)
+            with jax.named_scope("step.telemetry"):
+                dram = telemetry.tick_accrue(cfg, pool, snap, st, sched,
+                                             dram, t)
         if cfg.validate_enabled:
             # conservation laws hold as end-of-cycle identities
-            dram = dict(dram)
-            dram["viol"] = dram["viol"] + validate.tick_counts(
-                cfg, pool, pol, st, sched, dram, t)
+            with jax.named_scope("step.validate"):
+                dram = dict(dram)
+                dram["viol"] = dram["viol"] + validate.tick_counts(
+                    cfg, pool, pol, st, sched, dram, t)
         return (st, sched, dram), None
 
     return step
@@ -234,23 +259,28 @@ def make_skip_step(cfg: SimConfig, pol: MemoryPolicy, pool, active):
     def skip_body(carry, t, t_end):
         carry, _ = step(carry, t)
         st, sched, dram = carry
-        te = engine.next_source_event(cfg, pool, st, active, t)
-        te = jnp.minimum(te, engine.next_completion(dram, t))
-        te = jnp.minimum(te, pol.next_event(cfg, pool, st, sched, dram, t))
-        t_new = jnp.minimum(te, t_end)
-        k = t_new - t - 1                       # skipped cycles, >= 0
-        st = engine.skip_sources(cfg, pool, st, active, k)
-        if cfg.telemetry_enabled:
-            # before energy.skip_accrue: reads the pre-span pd_down
-            dram = telemetry.skip_accrue(cfg, pool, st, dram, t, t_new)
-        dram = energy.skip_accrue(cfg, dram, t, t_new)
-        if on_skip is not None:
-            sched = on_skip(cfg, sched, k)
+        with jax.named_scope("step.skip"):
+            te = engine.next_source_event(cfg, pool, st, active, t)
+            te = jnp.minimum(te, engine.next_completion(dram, t))
+            te = jnp.minimum(te, pol.next_event(cfg, pool, st, sched, dram,
+                                                t))
+            t_new = jnp.minimum(te, t_end)
+            k = t_new - t - 1                   # skipped cycles, >= 0
+            st = engine.skip_sources(cfg, pool, st, active, k)
+            if cfg.telemetry_enabled:
+                # before energy.skip_accrue: reads the pre-span pd_down
+                with jax.named_scope("step.telemetry"):
+                    dram = telemetry.skip_accrue(cfg, pool, st, dram, t,
+                                                 t_new)
+            dram = energy.skip_accrue(cfg, dram, t, t_new)
+            if on_skip is not None:
+                sched = on_skip(cfg, sched, k)
         if cfg.validate_enabled:
             # lateness audit of the jumped span, on post-accrual state
-            dram = dict(dram)
-            dram["viol"] = dram["viol"] + validate.span_counts(
-                cfg, pool, pol, st, sched, dram, active, t, t_new)
+            with jax.named_scope("step.validate"):
+                dram = dict(dram)
+                dram["viol"] = dram["viol"] + validate.span_counts(
+                    cfg, pool, pol, st, sched, dram, active, t, t_new)
         return (st, sched, dram), t_new
 
     return skip_body

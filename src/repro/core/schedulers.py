@@ -481,67 +481,81 @@ def make_stacked_step(cfg: SimConfig, pols, pool, active, cfgs=None,
     def step(carry, t):
         st, buf, dram = carry
         if cfg.telemetry_enabled:
-            snap = vP(telemetry.snapshot)(st, buf, dram)
-        st, dram = vP(lambda s, d: engine.completions_tick(s, d, t)
-                      )(st, dram)
-        if knobs is None:
-            dram = vP(lambda d: energy.background_tick(cfg, d, t))(dram)
-        else:
-            dram = vP(lambda d, kn: energy.background_tick(
-                params.bind(cfg, kn), d, t))(dram, knobs)
-        st = vP(lambda s: engine.deadline_tick(cfg, pool, s, t))(st)
-        st = vP(lambda s: engine.source_tick(cfg, pool, s, active, t))(st)
-        # admission: policy-ordered key per slice, one merged admit
-        key = jnp.stack([
-            p.admit_key(cfgs[i], pool, _slice_tree(st, i),
-                        _slice_tree(buf, i), t)
-            for i, p in enumerate(pols)])
-        if knobs is None:
-            st, buf, do, slot, src = vP(
-                lambda s, b, k: admit(cfg, pool, s, b, t, key=k)
-                )(st, buf, key)
-        else:
-            st, buf, do, slot, src = vP(
-                lambda s, b, k, kn: admit(params.bind(cfg, kn), pool, s, b,
-                                          t, key=k))(st, buf, key, knobs)
-        new = [p.tick_hooks(cfgs[i], pool, _slice_tree(st, i),
-                            _slice_tree(buf, i), do[i], slot[i], src[i], t)
-               for i, p in enumerate(pols)]
-        buf = {**buf, **{k: jnp.stack([n[k] for n in new])
-                         for k in tick_union}}
-        # selection: merged eligibility/issue, per-slice score + on_issue
-        elig, lat, is_hit = vP(
-            lambda b, d: eligibility_grid(cfg, b, d, t))(buf, dram)
-        score = jnp.stack([
-            p.score(cfgs[i], pool, _slice_tree(buf, i), is_hit[i], t)
-            for i, p in enumerate(pols)])
-        score = jnp.where(elig, score, -1)
-        st, dram, do, pick, src = vP(
-            lambda s, b, d, sc, la, hi: issue_picked(cfg, s, b, d, sc, la,
-                                                     hi, t)
-        )(st, buf, dram, score, lat, is_hit)
-        if issue_union:
-            new = [p.on_issue(cfgs[i], pool, _slice_tree(buf, i), do[i],
-                              pick[i], src[i], t)
+            with jax.named_scope("step.telemetry"):
+                snap = vP(telemetry.snapshot)(st, buf, dram)
+        with jax.named_scope("step.engine"):
+            st, dram = vP(lambda s, d: engine.completions_tick(s, d, t)
+                          )(st, dram)
+            if knobs is None:
+                dram = vP(lambda d: energy.background_tick(cfg, d, t))(dram)
+            else:
+                dram = vP(lambda d, kn: energy.background_tick(
+                    params.bind(cfg, kn), d, t))(dram, knobs)
+            st = vP(lambda s: engine.deadline_tick(cfg, pool, s, t))(st)
+            st = vP(lambda s: engine.source_tick(cfg, pool, s, active, t)
+                    )(st)
+        with jax.named_scope("step.admit"):
+            # policy-ordered key per slice, one merged admit
+            key = jnp.stack([
+                p.admit_key(cfgs[i], pool, _slice_tree(st, i),
+                            _slice_tree(buf, i), t)
+                for i, p in enumerate(pols)])
+            if knobs is None:
+                st, buf, do, slot, src = vP(
+                    lambda s, b, k: admit(cfg, pool, s, b, t, key=k)
+                    )(st, buf, key)
+            else:
+                st, buf, do, slot, src = vP(
+                    lambda s, b, k, kn: admit(params.bind(cfg, kn), pool, s,
+                                              b, t, key=k)
+                    )(st, buf, key, knobs)
+            new = [p.tick_hooks(cfgs[i], pool, _slice_tree(st, i),
+                                _slice_tree(buf, i), do[i], slot[i], src[i],
+                                t)
                    for i, p in enumerate(pols)]
             buf = {**buf, **{k: jnp.stack([n[k] for n in new])
-                             for k in issue_union}}
-        buf = vP(lambda b, d, pk, sr: clear_picked(cfg, pool, b, d, pk, sr)
-                 )(buf, do, pick, src)
+                             for k in tick_union}}
+        # selection: merged eligibility/issue, per-slice score + on_issue
+        with jax.named_scope("step.select"):
+            with jax.named_scope("select.eligibility"):
+                elig, lat, is_hit = vP(
+                    lambda b, d: eligibility_grid(cfg, b, d, t))(buf, dram)
+            with jax.named_scope("select.score"):
+                score = jnp.stack([
+                    p.score(cfgs[i], pool, _slice_tree(buf, i), is_hit[i], t)
+                    for i, p in enumerate(pols)])
+                score = jnp.where(elig, score, -1)
+            with jax.named_scope("select.issue"):
+                st, dram, do, pick, src = vP(
+                    lambda s, b, d, sc, la, hi: issue_picked(
+                        cfg, s, b, d, sc, la, hi, t)
+                )(st, buf, dram, score, lat, is_hit)
+                if issue_union:
+                    new = [p.on_issue(cfgs[i], pool, _slice_tree(buf, i),
+                                      do[i], pick[i], src[i], t)
+                           for i, p in enumerate(pols)]
+                    buf = {**buf, **{k: jnp.stack([n[k] for n in new])
+                                     for k in issue_union}}
+            with jax.named_scope("select.clear"):
+                buf = vP(lambda b, d, pk, sr: clear_picked(cfg, pool, b, d,
+                                                           pk, sr)
+                         )(buf, do, pick, src)
         if cfg.telemetry_enabled:
             # policy-independent accrual (no value knobs read): vmap over
             # P like the engine work rather than dispatching per slice
-            dram = vP(lambda sn, s, b, d: telemetry.tick_accrue(
-                cfg, pool, sn, s, b, d, t))(snap, st, buf, dram)
+            with jax.named_scope("step.telemetry"):
+                dram = vP(lambda sn, s, b, d: telemetry.tick_accrue(
+                    cfg, pool, sn, s, b, d, t))(snap, st, buf, dram)
         if cfg.validate_enabled:
             # conservation laws dispatch per slice like the other hooks
             # (policy invariants differ per policy object)
-            vio = jnp.stack([
-                _slice_tree(dram, i)["viol"] + validate.tick_counts(
-                    cfgs[i], pool, p, _slice_tree(st, i),
-                    _slice_tree(buf, i), _slice_tree(dram, i), t)
-                for i, p in enumerate(pols)])
-            dram = {**dram, "viol": vio}
+            with jax.named_scope("step.validate"):
+                vio = jnp.stack([
+                    _slice_tree(dram, i)["viol"] + validate.tick_counts(
+                        cfgs[i], pool, p, _slice_tree(st, i),
+                        _slice_tree(buf, i), _slice_tree(dram, i), t)
+                    for i, p in enumerate(pols)])
+                dram = {**dram, "viol": vio}
         return (st, buf, dram), None
 
     return step
@@ -571,52 +585,60 @@ def make_stacked_skip_step(cfg: SimConfig, pols, pool, active, cfgs=None,
     def skip_body(carry, t, t_end):
         carry, _ = step(carry, t)
         st, buf, dram = carry
-        te = jnp.min(vP(lambda s: engine.next_source_event(
-            cfg, pool, s, active, t))(st))
-        te = jnp.minimum(te, jnp.min(vP(
-            lambda d: engine.next_completion(d, t))(dram)))
-        if knobs is None:
+        with jax.named_scope("step.skip"):
+            te = jnp.min(vP(lambda s: engine.next_source_event(
+                cfg, pool, s, active, t))(st))
             te = jnp.minimum(te, jnp.min(vP(
-                lambda s, b: next_admission(cfg, pool, s, b, t))(st, buf)))
-        else:
-            # admission readiness reads gpu_cap, a value knob — thread the
-            # per-slice knob point through the vmapped witness
-            te = jnp.minimum(te, jnp.min(vP(
-                lambda s, b, kn: next_admission(params.bind(cfg, kn), pool,
-                                                s, b, t))(st, buf, knobs)))
-        te = jnp.minimum(te, jnp.min(vP(
-            lambda b, d: next_issue_ready(cfg, b, d, t))(buf, dram)))
-        for i, p in enumerate(pols):
-            nb = p.next_boundary(cfgs[i], pool, _slice_tree(st, i),
-                                 _slice_tree(buf, i), t)
-            if nb is not None:
-                te = jnp.minimum(te, nb)
-        t_new = jnp.minimum(te, t_end)
-        k = t_new - t - 1
-        st = vP(lambda s: engine.skip_sources(cfg, pool, s, active, k))(st)
-        if cfg.telemetry_enabled:
-            # before energy.skip_accrue (pre-span pd_down); the power-down
-            # entry threshold is a value knob, so bind per slice on grids
+                lambda d: engine.next_completion(d, t))(dram)))
             if knobs is None:
-                dram = vP(lambda s, d: telemetry.skip_accrue(
-                    cfg, pool, s, d, t, t_new))(st, dram)
+                te = jnp.minimum(te, jnp.min(vP(
+                    lambda s, b: next_admission(cfg, pool, s, b, t)
+                    )(st, buf)))
             else:
-                dram = vP(lambda s, d, kn: telemetry.skip_accrue(
-                    params.bind(cfg, kn), pool, s, d, t, t_new)
-                    )(st, dram, knobs)
-        if knobs is None:
-            dram = vP(lambda d: energy.skip_accrue(cfg, d, t, t_new))(dram)
-        else:
-            dram = vP(lambda d, kn: energy.skip_accrue(
-                params.bind(cfg, kn), d, t, t_new))(dram, knobs)
+                # admission readiness reads gpu_cap, a value knob — thread
+                # the per-slice knob point through the vmapped witness
+                te = jnp.minimum(te, jnp.min(vP(
+                    lambda s, b, kn: next_admission(params.bind(cfg, kn),
+                                                    pool, s, b, t)
+                    )(st, buf, knobs)))
+            te = jnp.minimum(te, jnp.min(vP(
+                lambda b, d: next_issue_ready(cfg, b, d, t))(buf, dram)))
+            for i, p in enumerate(pols):
+                nb = p.next_boundary(cfgs[i], pool, _slice_tree(st, i),
+                                     _slice_tree(buf, i), t)
+                if nb is not None:
+                    te = jnp.minimum(te, nb)
+            t_new = jnp.minimum(te, t_end)
+            k = t_new - t - 1
+            st = vP(lambda s: engine.skip_sources(cfg, pool, s, active, k)
+                    )(st)
+            if cfg.telemetry_enabled:
+                # before energy.skip_accrue (pre-span pd_down); the
+                # power-down entry threshold is a value knob, so bind per
+                # slice on grids
+                with jax.named_scope("step.telemetry"):
+                    if knobs is None:
+                        dram = vP(lambda s, d: telemetry.skip_accrue(
+                            cfg, pool, s, d, t, t_new))(st, dram)
+                    else:
+                        dram = vP(lambda s, d, kn: telemetry.skip_accrue(
+                            params.bind(cfg, kn), pool, s, d, t, t_new)
+                            )(st, dram, knobs)
+            if knobs is None:
+                dram = vP(lambda d: energy.skip_accrue(cfg, d, t, t_new)
+                          )(dram)
+            else:
+                dram = vP(lambda d, kn: energy.skip_accrue(
+                    params.bind(cfg, kn), d, t, t_new))(dram, knobs)
         if cfg.validate_enabled:
-            vio = jnp.stack([
-                _slice_tree(dram, i)["viol"] + validate.span_counts(
-                    cfgs[i], pool, p, _slice_tree(st, i),
-                    _slice_tree(buf, i), _slice_tree(dram, i), active,
-                    t, t_new)
-                for i, p in enumerate(pols)])
-            dram = {**dram, "viol": vio}
+            with jax.named_scope("step.validate"):
+                vio = jnp.stack([
+                    _slice_tree(dram, i)["viol"] + validate.span_counts(
+                        cfgs[i], pool, p, _slice_tree(st, i),
+                        _slice_tree(buf, i), _slice_tree(dram, i), active,
+                        t, t_new)
+                    for i, p in enumerate(pols)])
+                dram = {**dram, "viol": vio}
         return (st, buf, dram), t_new
 
     return skip_body
