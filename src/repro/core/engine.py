@@ -40,11 +40,12 @@ def addr_base(n_src: int, n_channels: int, n_banks: int) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# one-hot masked writes — the hot-loop replacement for scatter ops.
-# XLA:CPU lowers gather/scatter inside a scan body to serial per-element
-# loops; a compare-mask + select over the same (C, N) array fuses into the
-# surrounding elementwise work and is ~10x faster. All per-cycle state
-# updates with traced indices go through these.
+# one-hot masked writes and small-table reads — the hot-loop replacement
+# for scatter and gather ops. XLA:CPU lowers gather/scatter inside a scan
+# body to serial per-element loops, and the TPU runs a batched gather
+# element by element too; a compare-mask + select over the same (C, N)
+# array fuses into the surrounding elementwise work and is ~10x faster. All
+# per-cycle state updates with traced indices go through these.
 # ---------------------------------------------------------------------------
 
 def masked_set(a: jax.Array, idx: jax.Array, v, do: jax.Array) -> jax.Array:
@@ -84,6 +85,22 @@ def accum_by_index(acc: jax.Array, idx: jax.Array, v, do: jax.Array
     if jnp.ndim(v) == 1:
         v = v[:, None]
     return acc + jnp.sum(onehot.astype(acc.dtype) * v, axis=0)
+
+
+def small_lookup(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """out[..., n] = table[..., idx[..., n]] for a small last axis of table.
+
+    table: (..., K), its leading axes broadcastable against idx's leading
+    axes; idx: (..., N) int, every index in [0, K) (not clamped: an index
+    out of range reads table[..., 0]). A Python-unrolled chain of K - 1
+    selects with no arithmetic, so it is exact for every dtype (NaN
+    payloads and -0.0 included) and keeps the dtype, with N on the minor
+    axis.
+    """
+    out = jnp.broadcast_to(table[..., :1], idx.shape)
+    for k in range(1, table.shape[-1]):
+        out = jnp.where(idx == k, table[..., k:k + 1], out)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -417,25 +434,24 @@ def skip_sources(cfg: SimConfig, pool: Dict[str, jax.Array],
 # DRAM eligibility + issue
 # ---------------------------------------------------------------------------
 
-def eligibility(cfg: SimConfig, dram: Dict[str, Any], c: int,
-                bank: jax.Array, row: jax.Array, valid: jax.Array,
-                t: jax.Array):
-    """Per-candidate issue legality on channel c.
+def eligibility(cfg: SimConfig, dram: Dict[str, Any], bank: jax.Array,
+                row: jax.Array, valid: jax.Array, t: jax.Array):
+    """Per-candidate issue legality on every channel at once.
 
-    bank/row/valid: (N,) candidate arrays (bank is bank-in-channel index).
-    Returns (eligible (N,), lat (N,), is_hit (N,)).
+    bank/row/valid: (C, N) candidate arrays, row c on channel c (bank is
+    bank-in-channel index). Returns (eligible, lat, is_hit), each (C, N).
     """
     tm = cfg.timing
-    openv = dram["open_valid"][c][bank]
-    openr = dram["open_row"][c][bank]
+    openv = small_lookup(dram["open_valid"], bank)
+    openr = small_lookup(dram["open_row"], bank)
     is_hit = openv & (openr == row)
     lat = jnp.where(is_hit, tm.lat_hit,
                     jnp.where(openv, tm.lat_conflict, tm.lat_closed)
                     ).astype(jnp.int32)
-    ok_bank = dram["bank_free"][c][bank] <= t
-    oldest_act = jnp.min(dram["act_ring"][c])
+    ok_bank = small_lookup(dram["bank_free"] <= t, bank)
+    oldest_act = jnp.min(dram["act_ring"], axis=1, keepdims=True)
     ok_faw = is_hit | (t - oldest_act >= tm.t_faw)
-    ok_bus = t + lat >= dram["bus_free"][c]
+    ok_bus = t + lat >= dram["bus_free"][:, None]
     return valid & ok_bank & ok_faw & ok_bus, lat, is_hit
 
 

@@ -188,7 +188,7 @@ class CentralizedPolicy:
         """Default: cached per-source priority + FR-FCFS base score."""
         s = base_score(cfg, buf, is_hit, t)
         if "pri_src" in buf:
-            s = buf["pri_src"][buf["src"]] + s
+            s = engine.small_lookup(buf["pri_src"], buf["src"]) + s
         return s
 
     def on_admit(self, cfg: SimConfig, pool, st, buf, do, slot, src, t):
@@ -315,11 +315,8 @@ class CentralizedPolicy:
 
 def eligibility_grid(cfg: SimConfig, buf, dram, t):
     """Per-entry issue legality for every channel: (C, E) elig/lat/is_hit."""
-    cidx = jnp.arange(cfg.n_channels)
-    return jax.vmap(
-        lambda c, bank, row, valid: engine.eligibility(
-            cfg, dram, c, bank, row, valid, t)
-    )(cidx, buf["bank"], buf["row"], buf["valid"])
+    return engine.eligibility(cfg, dram, buf["bank"], buf["row"],
+                              buf["valid"], t)
 
 
 def issue_picked(cfg: SimConfig, st, buf, dram, score, lat, is_hit, t):

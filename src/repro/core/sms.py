@@ -229,9 +229,8 @@ def stage3_issue(cfg: SimConfig, st, sms, dram, t):
     src = at_head(sms["d_src"])
     birth = at_head(sms["d_birth"])
     valid = sms["d_len"] > 0
-    elig, lat, is_hit = jax.vmap(
-        lambda c, r, v: engine.eligibility(cfg, dram, c, jnp.arange(B), r,
-                                           v, t))(cidx, row, valid)
+    elig, lat, is_hit = engine.eligibility(
+        cfg, dram, jnp.broadcast_to(jnp.arange(B), (C, B)), row, valid, t)
     rr_key = jnp.where(elig, (jnp.arange(B)[None, :]
                               - sms["rr_bank"][:, None]) % B, 1 << 28)
     pick = jnp.argmin(rr_key, axis=1)                       # (C,)
@@ -357,10 +356,8 @@ def audit_skip(cfg: SimConfig, st, sms: Dict[str, Any], dram, t, t_new):
                                             2)[..., 0]
     row = at_head(sms["d_row"])
     valid = sms["d_len"] > 0
-    elig, _, _ = jax.vmap(
-        lambda c, r, v: engine.eligibility(
-            cfg, dram, c, jnp.arange(cfg.n_banks), r, v, u)
-    )(jnp.arange(cfg.n_channels), row, valid)
+    banks = jnp.broadcast_to(jnp.arange(cfg.n_banks), row.shape)
+    elig, _, _ = engine.eligibility(cfg, dram, banks, row, valid, u)
     b = lambda x: (skipped & x).astype(jnp.int32)
     return {"late_admission": b(s1), "late_boundary": b(s2),
             "late_issue": b(jnp.any(elig))}

@@ -10,6 +10,9 @@ the trace:
   * the ranked policies (atlas/tcm) actually HAVE their sorts behind a
     cond (the check isn't vacuous), while PAR-BS — reformulated to the
     amortized pairwise-rank form — has no sort primitive at all;
+  * the stacked step looks up its small per-bank and per-source tables in
+    `select.eligibility` and `select.score` with one-hot selects, never a
+    gather;
   * the scan carry holds only cycle-varying state: the read-only workload
     parameters `_pool`/`_active` are closed over, not carried;
   * the refactor is bit-identical: the golden digests for atlas/parbs/tcm
@@ -26,6 +29,7 @@ import pytest
 from repro import compat
 from repro.core import golden, params, validate
 from repro.core import policy as policy_api
+from repro.core import schedulers
 from repro.core import simulator as sim
 from repro.core.params import Knobs, SimConfig
 from repro.core.schedulers import CentralizedPolicy
@@ -91,6 +95,39 @@ def test_parbs_step_is_sort_free():
     jx = _step_jaxpr("parbs")
     sorts = [p for p, _ in _walk_prims(jx.jaxpr) if p in SORT_PRIMS]
     assert not sorts, f"parbs: {len(sorts)} sort op(s) — residue regressed"
+
+
+GATHER_FREE_SCOPES = {"select.eligibility", "select.score"}
+
+
+def _scoped_prims(jaxpr, outer=""):
+    """Yield (primitive_name, full name stack) over all nested jaxprs."""
+    for eqn in jaxpr.eqns:
+        stack = "/".join(filter(None, (outer,
+                                       str(eqn.source_info.name_stack))))
+        yield eqn.primitive.name, stack
+        for v in eqn.params.values():
+            for sub in compat.sub_jaxprs(v):
+                yield from _scoped_prims(sub, stack)
+
+
+@pytest.mark.parametrize("policy_name", _centralized_names())
+def test_stacked_select_lookups_build_no_gather(policy_name):
+    """The bank lookups of `engine.eligibility` and the per-source priority
+    lookup of `CentralizedPolicy.score` go through `engine.small_lookup`:
+    the stacked step has no gather under either scope, while gathers stay
+    elsewhere in it (the walk sees them)."""
+    pols, carry = sim._init_stacked(CFG, (policy_name,))
+    body = schedulers.make_stacked_step(
+        CFG, pols, _dummy_pool(CFG), jnp.ones((CFG.n_src,), bool))
+    prims = list(_scoped_prims(
+        jax.make_jaxpr(body)(carry, jnp.int32(5)).jaxpr))
+    scopes = {c for _, stack in prims for c in stack.split("/")}
+    assert GATHER_FREE_SCOPES <= scopes
+    assert any(p == "gather" for p, _ in prims)
+    bad = [stack for p, stack in prims if p == "gather"
+           and GATHER_FREE_SCOPES & set(stack.split("/"))]
+    assert not bad, f"{policy_name}: {len(bad)} gather(s): {bad}"
 
 
 def test_energy_accounting_adds_no_sorts_or_scatters():

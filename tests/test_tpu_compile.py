@@ -1,13 +1,17 @@
-"""The headline sweep's solo programs compile for a TPU v5e chip.
+"""The headline sweep's programs compile for a TPU v5e chip.
 
 Compiles `simulator._sim_batch` at the headline shape (parity config, 105
-mixes plus the alone baselines, 16k+2k cycles) for one chip of a described
-v5e:2x2 topology: nothing runs, but the TPU compiler must accept each
-program and its memory must fit the chip. The topology is described inside
-a module fixture (never at import, in a skipif or in parametrize) and the
-persistent compilation cache is off around the compiles.
+mixes plus the alone baselines, 16k+2k cycles), and the stacked family
+program `simulator._sim_batch_stacked` at the benchmark cell's shape (16
+CPUs + 1 GPU on 4 channels, 134 entries, 128 rows, six policies), for one
+chip of a described v5e:2x2 topology: nothing runs, but the TPU compiler
+must accept each program and its memory must fit the chip. The topology is
+described inside a module fixture (never at import, in a skipif or in
+parametrize) and the persistent compilation cache is off around the
+compiles.
 """
 import os
+import re
 
 import jax
 import numpy as np
@@ -40,10 +44,10 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _headline_args(sharding):
+def _headline_args(sharding, cfg=None):
     """Shape structs of the headline batch (alone rows + workload rows),
     as `simulate_async` hands them to `_sim_batch`."""
-    cfg = common.parity_config()
+    cfg = common.parity_config() if cfg is None else cfg
     wls = wl.make_workloads(cfg.n_cpu, n_per_cat=N_PER_CAT)
     pool, active = wl.pool_batch(cfg, wls)
     apool, aactive, _ = wl.alone_batch(cfg)
@@ -63,7 +67,36 @@ def test_headline_program_compiles_for_v5e(one_chip, policy, skip):
     compiled = sim._sim_batch.lower(cfg, policy, N_CYCLES, WARMUP,
                                     sim.DEFAULT_UNROLL, skip, pool,
                                     active).compile()
+    _assert_fits(compiled)
+
+
+def _assert_fits(compiled):
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
     assert 0 < total < V5E_HBM_BYTES, mem
+
+
+def test_stacked_family_compiles_for_v5e_without_select_gathers(one_chip):
+    """The benchmark cell's stacked program: it fits the chip, and its
+    optimized HLO keeps no gather under `select.eligibility` or
+    `select.score` (their small-table lookups are one-hot selects)."""
+    cfg = common.parity_config(n_cpu=16, n_channels=4)
+    assert cfg.buf_entries == 134
+    cfg, pool, active = _headline_args(one_chip, cfg)
+    assert active.shape[0] == 128
+    policies = sim.stackable_names(cfg)
+    assert len(policies) == 6
+    compiled = sim._sim_batch_stacked.lower(
+        cfg, policies, 2_000, 500, sim.DEFAULT_UNROLL, False, pool,
+        active).compile()
+    _assert_fits(compiled)
+    hlo = compiled.as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', hlo)
+    assert any("/select.eligibility/" in o for o in op_names)
+    gathers = [m.group(1) for m in re.finditer(
+        r'= \S+ gather\(.*?op_name="([^"]*)"', hlo)]
+    assert gathers                          # select.issue still gathers
+    bad = [o for o in gathers
+           if {"select.eligibility", "select.score"} & set(o.split("/"))]
+    assert not bad, bad
