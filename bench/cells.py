@@ -67,16 +67,22 @@ def _applies(metric: Dict[str, Any], cell: str) -> bool:
 
 
 def load_cell(name: str, root: Path = ROOT) -> Cell:
-    """The cell `name` of `BENCHMARK.json`, with its files read by name."""
+    """The cell `name` of `BENCHMARK.json`, with its files read by name.
+    A configuration has one GPU or none: the reference models no more."""
     bench = load_benchmark(root)
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
         raise KeyError(f"unknown workload {name!r}; known: {sorted(cells)}")
     w = cells[name]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    config = _load(root / conf["file"])
+    n_gpu = config["sim_config"]["n_gpu"]
+    if n_gpu not in (0, 1):
+        raise ValueError(
+            f"configuration {conf['name']!r} ({conf['file']}) has n_gpu "
+            f"{n_gpu!r}; the benchmark models one GPU (1) or none (0)")
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=_load(root / conf["file"]),
+        name=name, chips=int(w["chips"]), config=config,
         traffic=_load(root / "bench" / "traffic" / f"{w['traffic']}.json"),
         end_to_end={m["name"]: m["unit"] for m in bench["end_to_end"]
                     if _applies(m, name)},
@@ -101,14 +107,17 @@ def population(cell: Cell, seed: int, sweep: int) -> List[Any]:
     return rwl.make_workloads(int(cell.sim_fields["n_cpu"]),
                               n_per_cat=int(pop["n_per_cat"]),
                               seed=population_seed(seed, sweep),
-                              n_hwa=int(pop["hwa_per_mix"]))
+                              n_hwa=int(pop["hwa_per_mix"]),
+                              n_gpu=int(cell.sim_fields["n_gpu"]))
 
 
 def n_alone_rows(cell: Cell) -> int:
     from simref import workloads as rwl
 
-    return len(rwl.CPU_BENCH) + len(rwl.GPU_BENCH) + \
-        (len(rwl.HWA_BENCH) if int(cell.sim_fields["n_hwa"]) > 0 else 0)
+    f = cell.sim_fields
+    return len(rwl.CPU_BENCH) + \
+        (len(rwl.GPU_BENCH) if int(f["n_gpu"]) > 0 else 0) + \
+        (len(rwl.HWA_BENCH) if int(f["n_hwa"]) > 0 else 0)
 
 
 def cycle_workloads(cell: Cell) -> int:

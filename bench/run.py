@@ -17,10 +17,10 @@ A cell (`BENCHMARK.json` "workloads") is one configuration
    `--seconds` have passed. Sweep k's mixes are drawn from (seed, k), so no
    sweep repeats another's inputs. `cycle_wl_per_s` is every simulated
    cycle-workload of the window's sweeps over the seconds from the first
-   sweep's call to the last one's return. With `--trace 1` the window holds
-   at least two sweeps, the profiler samples sweep 2 in short bursts
-   (`TraceSampler`), and the per-layer metrics are read from those and from
-   set-up's compile events instead.
+   sweep's call to the last one's return. With `--trace 1` the profiler
+   samples the sweeps after sweep 1 in short bursts (`TraceSampler`), the
+   window runs on until it has taken them, and the per-layer metrics are
+   read from those and from set-up's compile events instead.
 3. check: the pools the program built for every sweep must equal the
    benchmark's own (`simref.workloads`); every policy's result of one
    window sweep drawn from the seed must equal the plain reference's
@@ -48,7 +48,7 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
 from pathlib import Path  # noqa: E402
-from typing import Any, Dict, List, Optional  # noqa: E402
+from typing import Any, Dict, List, NamedTuple, Optional  # noqa: E402
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -85,7 +85,8 @@ def load_metric_reader(name: str, root: Path = ROOT):
 
 
 def program_mixes(mixes) -> List[Any]:
-    """The benchmark's mixes as the program's `Workload` records."""
+    """The benchmark's mixes as the program's `Workload` records, `gpu_id`
+    as drawn (-1 where the configuration has no GPU)."""
     from repro.core import workloads as wl
 
     return [wl.Workload(m.category, tuple(int(i) for i in m.cpu_ids),
@@ -119,63 +120,149 @@ def traffic_mismatch(cfg, rcfg, mixes) -> int:
             + diff({"a": aa}, {"a": ba}) + (0 if am == bm else len(bm)))
 
 
-class TraceSampler:
-    """Runs the JAX profiler in short bursts spread evenly over window
-    sweep 2 (a traced run's window holds at least two sweeps): `BURSTS`
-    bursts of `BURST_S` seconds, one every 1/`BURSTS` of sweep 1's length
-    from sweep 2's start, each its own session under `trace_dir`. A whole
-    sweep runs millions of device operations, whose trace takes minutes to
-    write; the bursts hold about a tenth of them, and every program that
-    runs for a tenth of the sweep or more falls in at least one."""
+class JaxProfiler:
+    """One profiler session per burst: `jax.profiler` with the Python
+    tracer off."""
 
-    BURSTS, BURST_S = 16, 0.12
-
-    def __init__(self, trace_dir: Path):
-        self.trace_dir = trace_dir
-        self.stop = threading.Event()
-        self.activity = "host"            # what the main thread is doing
-        self.bursts: List = []            # (session dir, activity at start)
-        self.thread: Optional[threading.Thread] = None
-        shutil.rmtree(trace_dir, ignore_errors=True)
-
-    def start(self, sweep_s: float) -> None:
-        """Called as sweep 2 begins; `sweep_s` is sweep 1's length."""
-        self.thread = threading.Thread(
-            target=self._run,
-            args=(time.perf_counter(), sweep_s / self.BURSTS))
-        self.thread.start()
-
-    def _run(self, t0: float, every: float) -> None:
+    def start(self, path: Path) -> None:
         import jax
 
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
-        for i in range(self.BURSTS):
-            wait = t0 + i * every - time.perf_counter()
-            if wait < -every:             # a slow write put it a slot late
-                continue
-            if self.stop.wait(max(wait, 0.0)):
+        jax.profiler.start_trace(str(path), profiler_options=opts)
+
+    def stop(self) -> None:
+        import jax
+
+        jax.profiler.stop_trace()
+
+
+class Burst(NamedTuple):
+    path: Path                  # the session's directory
+    label: str                  # what the main thread was doing at its start
+    bin: int
+    phase: float                # its start, in sweeps from its sweep's start
+    cost_s: float               # the burst and its write
+
+
+class TraceSampler:
+    """Runs the profiler in short bursts over the window sweeps after sweep
+    1, each burst its own session under `trace_dir`. A whole sweep runs
+    millions of device operations, whose trace takes minutes to write, and
+    even a short burst takes longer to write than to run, so the bursts are
+    spread over as many sweeps as they need.
+
+    The plan is `BINS` bursts of `BURST_S` seconds, bin b starting b/`BINS`
+    of the way into a sweep (sweep 1's length, counted from the running
+    sweep's start). Together they cover the whole sweep, and every program
+    that runs for a tenth of a sweep or more falls in two bursts or more.
+    After a burst and its write, the sampler takes the first bin not yet
+    taken that still lies ahead in the running sweep (or began less than a
+    quarter of a bin ago), or else the first one left in the next sweep; so
+    no two bursts are closer than a burst and its write. It stops once every
+    bin is taken, or once `MAX_S` seconds have passed since sweep 2 began;
+    `summary` gives the bursts taken beside those planned. `activity` names
+    what the main thread is doing, and each burst keeps the activity at its
+    start as its label."""
+
+    BINS, BURST_S, MAX_S = 20, 0.12, 150.0
+
+    def __init__(self, trace_dir: Path, profiler=None):
+        self.trace_dir = trace_dir
+        self.profiler = profiler if profiler is not None else JaxProfiler()
+        self.cond = threading.Condition()
+        self.stopped = False
+        self.activity = "host"
+        self.sweep_s = 0.0
+        self.starts: List[float] = []     # start of each sweep from sweep 2
+        self.bursts: List[Burst] = []
+        self.thread: Optional[threading.Thread] = None
+        shutil.rmtree(trace_dir, ignore_errors=True)
+
+    def population(self) -> None:
+        self.activity = "bench.population"
+
+    def sweep(self, k: int, sweep_s: float) -> None:
+        """Called as window sweep `k` begins; `sweep_s` is sweep 1's length.
+        The bursts start with sweep 2."""
+        with self.cond:
+            self.activity = f"bench.sweep {k}"
+            if k < 2:
                 return
-            d = self.trace_dir / f"burst{i:02d}"
-            self.bursts.append((d, self.activity))
-            jax.profiler.start_trace(str(d), profiler_options=opts)
+            self.starts.append(time.perf_counter())
+            self.cond.notify_all()
+        if self.thread is None:
+            self.sweep_s = sweep_s
+            self.thread = threading.Thread(target=self._run)
+            self.thread.start()
+
+    def running(self) -> bool:
+        return self.thread is not None and self.thread.is_alive()
+
+    def _wait(self, until, timeout: Optional[float]) -> bool:
+        """Waits for `until()` or the timeout; True once stopped."""
+        with self.cond:
+            self.cond.wait_for(lambda: self.stopped or until(), timeout)
+            return self.stopped
+
+    def _run(self) -> None:
+        left = list(range(self.BINS))
+        t_end = self.starts[0] + self.MAX_S
+        while left:
+            n = len(self.starts)
+            s0 = self.starts[-1]
+            now = time.perf_counter()
+            w = self.sweep_s / self.BINS
+            ahead = [b for b in left if s0 + (b + 0.25) * w >= now]
+            if not ahead:                 # to the next sweep
+                if self._wait(lambda: len(self.starts) > n,
+                              max(t_end - now, 0.0)) or \
+                        len(self.starts) == n:
+                    return
+                continue
+            b = ahead[0]
+            at = s0 + b * w
+            if at > t_end or self._wait(lambda: len(self.starts) > n,
+                                        max(at - now, 0.0)):
+                return
+            if len(self.starts) > n:      # the sweep ended before the bin
+                continue
+            t0 = time.perf_counter()
+            label = self.activity
+            d = self.trace_dir / f"burst{len(self.bursts):03d}"
+            self.profiler.start(d)
             try:
-                self.stop.wait(self.BURST_S)
+                self._wait(lambda: False, self.BURST_S)
             finally:
-                jax.profiler.stop_trace()
+                self.profiler.stop()
+            self.bursts.append(Burst(d, label, b, (t0 - s0) / self.sweep_s,
+                                     time.perf_counter() - t0))
+            left.remove(b)
 
     def close(self) -> None:
         """Ends the bursts at the window's end at the latest."""
-        self.stop.set()
+        with self.cond:
+            self.stopped = True
+            self.cond.notify_all()
         if self.thread is not None:
             self.thread.join()
+
+    def summary(self) -> str:
+        cost = sorted(b.cost_s for b in self.bursts)
+        spread = f"{cost[0]:.3f}-{cost[-1]:.3f}" if cost else "-"
+        size = sum(f.stat().st_size for f in self.trace_dir.rglob("*")
+                   if f.is_file())
+        return (f"took {len(self.bursts)} of {self.BINS} planned bursts of "
+                f"{self.BURST_S} s over {len(self.starts)} sweeps "
+                f"({self.BINS - len(self.bursts)} not reached), {size} bytes; "
+                f"burst and write {spread} s; bins "
+                f"{sorted(b.bin for b in self.bursts)}")
 
     def sample(self):
         import trace_reduce as tr
 
-        return tr.Sample([tr.load_xplane(p, label)
-                          for d, label in self.bursts
-                          for p in tr.xplane_paths(str(d))])
+        return tr.Sample([tr.load_xplane(p, b.label) for b in self.bursts
+                          for p in tr.xplane_paths(str(b.path))])
 
 
 class Listener:
@@ -251,22 +338,21 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool,
     ends: List[float] = []
     t0 = time.perf_counter()
     sampler = TraceSampler(trace_dir) if trace else None
-    while time.perf_counter() - t0 < seconds or \
-            (sampler is not None and len(done) < 2):
+    while time.perf_counter() - t0 < seconds or (sampler is not None and (
+            len(done) < 2 or sampler.running())):
         k = len(done) + 1
         if sampler is not None:
-            sampler.activity = "bench.population"
-            if k == 2:
-                sampler.start(ends[0])
+            sampler.population()
         with jax.profiler.TraceAnnotation("bench.population"):
             mixes = cells.population(cell, seed, k)
         if sampler is not None:
-            sampler.activity = f"bench.sweep {k}"
+            sampler.sweep(k, ends[0] if ends else 0.0)
         done.append((mixes, sweep(mixes, k)))
         ends.append(time.perf_counter() - t0)
     window_s = ends[-1]
     if sampler is not None:
         sampler.close()
+        _say(f"{cell.name}: trace {sampler.summary()}")
     stats = devs[0].memory_stats() or {}
     mem_peak = stats.get("peak_bytes_in_use")
     rate = per_sweep * len(done) / window_s
@@ -322,11 +408,13 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool,
             value = load_metric_reader(name, cell.root)(ctx)
             if value is not None:
                 metrics[name] = {"value": value, "unit": unit}
-        device["busy_s"] = t.busy_s()
-        device["window_s"] = t.window_s()
+        swept = t.in_sweeps()
+        device["busy_s"] = swept.busy_s()
+        device["window_s"] = swept.window_s()
         _say(f"{cell.name}: traced {len(t.traces)} bursts, "
-             f"{device['window_s']:.6f} s, busy {device['busy_s']:.6f} s; "
-             f"per family {t.family_stats()}")
+             f"{len(swept.traces)} inside a sweep: {device['window_s']:.6f} "
+             f"s, busy {device['busy_s']:.6f} s; per family "
+             f"{t.family_stats()}; bursts holding each {t.bursts_holding()}")
         result["metrics"] = metrics
         result["device"] = device
         result["breakdown"] = {"device_ops": t.top_ops(10),

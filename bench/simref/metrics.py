@@ -36,7 +36,8 @@ def per_source_alone(cfg: SimConfig, wl: Workload,
     out = np.ones((cfg.n_src,), np.float64)
     for i, b in enumerate(wl.cpu_ids[:cfg.n_cpu]):
         out[i] = max(alone[CPU_BENCH[b][0]], 1e-9)
-    out[cfg.n_cpu] = max(alone[GPU_BENCH[wl.gpu_id][0]], 1e-9)
+    if cfg.n_gpu > 0:
+        out[cfg.n_cpu] = max(alone[GPU_BENCH[wl.gpu_id][0]], 1e-9)
     for j, b in enumerate(wl.hwa_ids[:cfg.n_hwa]):
         out[cfg.n_cpu + cfg.n_gpu + j] = max(alone[HWA_BENCH[b][0]], 1e-9)
     return out
@@ -46,30 +47,35 @@ def workload_metrics(cfg: SimConfig, wl: Workload, shared_perf: np.ndarray,
                      alone: Dict[str, float]) -> Dict[str, float]:
     """shared_perf: (S,) per-source perf (IPC for CPUs, BW for GPU/HWAs).
 
-    The populated sources are the n_cpu CPUs, the GPU at index n_cpu, and
-    the workload's HWAs; slowdown reductions run over exactly those, with
-    the per-class variants masking the shared `max_slowdown` reduction.
-    `weighted_speedup` keeps its 2-class CPU+GPU definition (the paper's
-    headline metric); HWA throughput reports separately as `hwa_speedup`.
+    The populated sources are the n_cpu CPUs, the GPU at index n_cpu where
+    the configuration has one, and the workload's HWAs; slowdown reductions
+    run over exactly those, with the per-class variants masking the shared
+    `max_slowdown` reduction. `weighted_speedup` keeps its 2-class CPU+GPU
+    definition (the paper's headline metric), the CPUs' alone with no GPU;
+    HWA throughput reports separately as `hwa_speedup`. An absent class's
+    columns are left out (`gpu_speedup`, `hwa_*`).
     """
     alone_v = per_source_alone(cfg, wl, alone)
     ratio = np.maximum(shared_perf, 1e-9) / alone_v
     n = cfg.n_cpu
+    gpu = [n] if cfg.n_gpu > 0 else []
     n_hwa = len(wl.hwa_ids[:cfg.n_hwa])
-    idx = np.asarray(list(range(n)) + [n] +
+    idx = np.asarray(list(range(n)) + gpu +
                      [n + cfg.n_gpu + j for j in range(n_hwa)])
-    cls = np.asarray([CLS_CPU] * n + [CLS_GPU] + [CLS_HWA] * n_hwa)
+    cls = np.asarray([CLS_CPU] * n + [CLS_GPU] * len(gpu)
+                     + [CLS_HWA] * n_hwa)
     slowdowns = 1.0 / np.maximum(ratio[idx], 1e-9)
     cpu_ws = float(ratio[:n].sum())
-    gpu_su = float(ratio[n])
-    out = {
-        "weighted_speedup": cpu_ws + gpu_su,
-        "cpu_weighted_speedup": cpu_ws,
-        "gpu_speedup": gpu_su,
+    out = {"weighted_speedup": cpu_ws, "cpu_weighted_speedup": cpu_ws}
+    if gpu:
+        gpu_su = float(ratio[n])
+        out["weighted_speedup"] = cpu_ws + gpu_su
+        out["gpu_speedup"] = gpu_su
+    out.update({
         "max_slowdown": max_slowdown(slowdowns),
         "cpu_max_slowdown": max_slowdown(slowdowns, cls == CLS_CPU),
         "harmonic_speedup": float(len(idx) / (1.0 / ratio[idx]).sum()),
-    }
+    })
     if n_hwa:
         out["hwa_speedup"] = float(ratio[idx[cls == CLS_HWA]].sum())
         out["hwa_max_slowdown"] = max_slowdown(slowdowns, cls == CLS_HWA)

@@ -59,15 +59,16 @@ _CAT_GROUPS = {
 class Workload:
     category: str
     cpu_ids: Tuple[int, ...]   # indices into CPU_BENCH
-    gpu_id: int                # index into GPU_BENCH
+    gpu_id: int                # index into GPU_BENCH; -1 with no GPU
     hwa_ids: Tuple[int, ...] = ()   # indices into HWA_BENCH
 
 
 def make_workloads(n_cpu: int, n_per_cat: int = 15, seed: int = 7,
-                   n_hwa: int = 0) -> List[Workload]:
-    """`n_hwa > 0` adds that many HWA draws per workload. The draws happen
-    only when requested, so the 2-class workload stream for a given seed is
-    unchanged by the N-class extension."""
+                   n_hwa: int = 0, n_gpu: int = 1) -> List[Workload]:
+    """`n_hwa > 0` adds that many HWA draws per workload, and `n_gpu = 0`
+    takes out the GPU draw (`gpu_id` -1). The draws happen only when
+    requested, in the same order, so the CPU+GPU workload stream for a given
+    seed is unchanged by either."""
     rng = np.random.RandomState(seed)
     by_group: Dict[str, List[int]] = {"l": [], "m": [], "h": []}
     for i, (name, *_ ) in enumerate(CPU_BENCH):
@@ -77,7 +78,7 @@ def make_workloads(n_cpu: int, n_per_cat: int = 15, seed: int = 7,
         pool = [i for g in _CAT_GROUPS[cat] for i in by_group[g]]
         for _ in range(n_per_cat):
             cpu_ids = tuple(rng.choice(pool, size=n_cpu, replace=True))
-            gpu_id = int(rng.randint(len(GPU_BENCH)))
+            gpu_id = int(rng.randint(len(GPU_BENCH))) if n_gpu else -1
             hwa_ids = tuple(int(rng.randint(len(HWA_BENCH)))
                             for _ in range(n_hwa)) if n_hwa else ()
             out.append(Workload(cat, cpu_ids, gpu_id, hwa_ids))
@@ -86,7 +87,9 @@ def make_workloads(n_cpu: int, n_per_cat: int = 15, seed: int = 7,
 
 def pool_batch(cfg: SimConfig, workloads: Sequence[Workload]
                ) -> Tuple[Dict[str, np.ndarray], np.ndarray]:
-    """Build (pool arrays (W,S), active (W,S)) for the shared runs."""
+    """Build (pool arrays (W,S), active (W,S)) for the shared runs: the
+    CPUs at sources 0..n_cpu-1, the GPU (where the configuration has one) at
+    n_cpu, HWA j at n_cpu + n_gpu + j."""
     W, S = len(workloads), cfg.n_src
     mpki = np.zeros((W, S), np.float32)
     rbl = np.zeros((W, S), np.float32)
@@ -100,11 +103,12 @@ def pool_batch(cfg: SimConfig, workloads: Sequence[Workload]
         for i, b in enumerate(wl.cpu_ids[:cfg.n_cpu]):
             _, m, r, bl = CPU_BENCH[b]
             mpki[w, i], rbl[w, i], blp[w, i] = m, r, bl
-        gname, gr, gb = GPU_BENCH[wl.gpu_id]
-        gi = cfg.n_cpu
-        mpki[w, gi], rbl[w, gi], blp[w, gi] = 1000.0, gr, gb
-        is_gpu[w, gi] = True
-        src_class[w, gi] = CLS_GPU
+        if cfg.n_gpu > 0:
+            _, gr, gb = GPU_BENCH[wl.gpu_id]
+            gi = cfg.n_cpu
+            mpki[w, gi], rbl[w, gi], blp[w, gi] = 1000.0, gr, gb
+            is_gpu[w, gi] = True
+            src_class[w, gi] = CLS_GPU
         for j, b in enumerate(wl.hwa_ids[:cfg.n_hwa]):
             _, period, reqs, r, bl, jit = HWA_BENCH[b]
             hi = cfg.n_cpu + cfg.n_gpu + j
@@ -134,10 +138,13 @@ def alone_batch(cfg: SimConfig) -> Tuple[Dict[str, np.ndarray], np.ndarray,
                                          Dict[str, int]]:
     """One single-source run per benchmark; returns index map name->row.
 
-    HWA rows are added only when the config has HWA slots (cfg.n_hwa > 0),
-    keeping the 2-class alone sweep — and its cached results — untouched.
+    GPU rows are added only when the config has a GPU (cfg.n_gpu > 0), HWA
+    rows only when it has HWA slots (cfg.n_hwa > 0), keeping the 2-class
+    alone sweep — and its cached results — untouched.
     """
-    names = [b[0] for b in CPU_BENCH] + [g[0] for g in GPU_BENCH]
+    names = [b[0] for b in CPU_BENCH]
+    if cfg.n_gpu > 0:
+        names += [g[0] for g in GPU_BENCH]
     if cfg.n_hwa > 0:
         names += [h[0] for h in HWA_BENCH]
     W, S = len(names), cfg.n_src

@@ -98,3 +98,13 @@ def test_no_device_ops_reads_nothing():
     assert host.top_ops() == []
     assert host.idle_gaps() == [["host", pytest.approx(1600e-9)]]
 
+
+
+def test_bursts_holding_each_family():
+    hand = tr.Trace(hand_trace())
+    stacked_only = tr.Trace([e for e in hand_trace()
+                             if e.start_ns < 1000 or e.plane != DEV])
+    quiet = tr.Trace([], span=(0.0, 400.0), label="bench.population")
+    s = tr.Sample([hand, stacked_only, quiet])
+    assert s.bursts_holding() == {"_sim_batch_stacked": 2, "_sim_batch": 1}
+    assert tr.Sample([quiet]).bursts_holding() == {}

@@ -29,11 +29,13 @@ import os
 import statistics
 import sys
 from collections import defaultdict
+from functools import cached_property
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 HOST_PREFIX = "bench."
+SWEEP_LABEL = "bench.sweep "
 
 
 class Event(NamedTuple):
@@ -258,6 +260,13 @@ class Sample:
     def __init__(self, traces: Sequence[Trace]):
         self.traces = list(traces)
 
+    def in_sweeps(self) -> "Sample":
+        """The bursts that began inside a window sweep (label `bench.sweep
+        <k>`): the device's own idle time, without the host's work between
+        sweeps."""
+        return Sample([t for t in self.traces
+                       if t.label.startswith(SWEEP_LABEL)])
+
     def busy_s(self) -> float:
         return sum(t.busy_s() for t in self.traces)
 
@@ -270,13 +279,25 @@ class Sample:
             return None
         return 1.0 - self.busy_s() / self.window_s()
 
+    @cached_property
+    def _family_stats(self) -> List[Dict[str, Dict[str, float]]]:
+        return [t.family_stats() for t in self.traces]
+
     def family_stats(self) -> Dict[str, Dict[str, float]]:
         out: Dict[str, Dict[str, float]] = defaultdict(
             lambda: {"op_ns": 0.0, "iterations": 0.0})
-        for t in self.traces:
-            for fam, st in t.family_stats().items():
+        for stats in self._family_stats:
+            for fam, st in stats.items():
                 for k, v in st.items():
                     out[fam][k] += v
+        return dict(out)
+
+    def bursts_holding(self) -> Dict[str, int]:
+        """{module family: bursts in which a run of it ran a leaf op}."""
+        out: Dict[str, int] = defaultdict(int)
+        for stats in self._family_stats:
+            for fam in stats:
+                out[fam] += 1
         return dict(out)
 
     def top_ops(self, n: int = 10) -> List[List]:
