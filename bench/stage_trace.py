@@ -31,6 +31,7 @@ them.
 """
 from __future__ import annotations
 
+import importlib
 import re
 import sys
 from collections import defaultdict
@@ -97,17 +98,22 @@ def hlo_op_names(hlo_text: str) -> Dict[str, str]:
 def stacked_op_names(cell) -> Dict[str, str]:
     """The instruction op_names of the cell's stacked family program, lowered
     and compiled again as `run_sweep` dispatches it: the alone rows, then
-    the cell's number of mixes, under the program's default driver."""
+    the mixes of one sweep, drawn by the benchmark's own sampler and handed
+    to the program as the window hands them (`run.program_mixes`, so a
+    configuration with no GPU has no GPU draw), under the program's default
+    driver. The pools are traced arguments, so the program depends on their
+    shapes alone, not on the draws."""
     import numpy as np
+
+    import cells
     from repro.core import params
     from repro.core import simulator as sim
     from repro.core import workloads as wl
 
     f = dict(cell.sim_fields)
     cfg = params.SimConfig(timing=params.Timing(**f.pop("timing")), **f)
-    pop = cell.traffic["population"]
-    mixes = wl.make_workloads(cfg.n_cpu, int(pop["n_per_cat"]),
-                              n_hwa=int(pop["hwa_per_mix"]))
+    run = harness() or importlib.import_module("run")
+    mixes = run.program_mixes(cells.population(cell, 0, 0))
     pool, active = wl.pool_batch(cfg, mixes)
     apool, aactive, _ = wl.alone_batch(cfg)
     pool = {k: np.concatenate([apool[k], pool[k]]) for k in pool}
@@ -252,15 +258,21 @@ class StageSample:
         return dict(out)
 
 
-def burst_dir(cell) -> Optional[Path]:
-    """Where the running harness's `TraceSampler` wrote the cell's bursts:
-    `CACHE / "trace" / <cell>` of `run` (imported, as in the tests) or of
-    `__main__` (`python3 bench/run.py`)."""
+def harness():
+    """The running harness: `run` (imported, as in the tests) or `__main__`
+    (`python3 bench/run.py`); None where neither is loaded."""
     for name in ("run", "__main__"):
         mod = sys.modules.get(name)
         if mod is not None and hasattr(mod, "TraceSampler"):
-            return Path(mod.CACHE) / "trace" / cell.name
+            return mod
     return None
+
+
+def burst_dir(cell) -> Optional[Path]:
+    """Where the running harness's `TraceSampler` wrote the cell's bursts:
+    its `CACHE / "trace" / <cell>`."""
+    run = harness()
+    return None if run is None else Path(run.CACHE) / "trace" / cell.name
 
 
 # the last run's reading, shared by its readers: (its Sample, the reading)

@@ -9,7 +9,9 @@ alone pools of `paper_16c4ch`, and of `reference.assemble` on a fixed
 synthetic set of per-row statistics (keys and bits).
 
 With none there is no GPU slot: HWA `j` sits at source `n_cpu + j`, there
-are no GPU alone rows, and the GPU term leaves the metrics.
+are no GPU alone rows, and the GPU term leaves the metrics. A program that
+builds a GPU such a cell does not have is caught, the fault planted here
+over the program's own builders.
 """
 import hashlib
 import io
@@ -317,12 +319,76 @@ def test_gpuless_cell_is_found_by_name(tmp_path):
     assert cells.cycle_workloads(cell) == 50 * (7 + 22) * 2
 
 
-def test_gpuless_cell_against_todays_program(tmp_path):
-    """Until the program learns a configuration with no GPU, it builds one
-    where the benchmark has none: the run reads `correct: false` through
-    `traffic_mismatch`."""
-    from repro.core import params
+def test_per_layer_metrics_apply_to_every_cell(tmp_path):
+    """No per-layer metric is tied to a cell: a GPU-less cell added as new
+    files gets all nine, and fig4 the nine it had."""
+    nine = {"device_idle_share": "fraction", "trace_lower_s": "s",
+            "compile_load_s": "s", "stacked_us_per_cycle": "us",
+            "solo_us_per_cycle": "us", "stacked_admit_us_per_cycle": "us",
+            "stacked_select_us_per_cycle": "us",
+            "stacked_engine_us_per_cycle": "us", "sweep_host_s": "s"}
+    root = scratch_checkout(tmp_path)
+    assert cells.load_cell("soc.tiny", root=root).per_layer == nine
+    assert cells.load_cell(FIG4).per_layer == nine
 
+
+def plant_gpu(pool, rows, slot, bench):
+    """Writes GPU `bench` of the program's table at source `slot` of
+    `rows`."""
+    from repro.core import workloads as wl
+
+    _, rbl, blp = wl.GPU_BENCH[bench]
+    pool["mpki"][rows, slot], pool["rbl"][rows, slot] = 1000.0, rbl
+    pool["blp"][rows, slot], pool["inst_per_miss"][rows, slot] = blp, 1.0
+    pool["is_gpu"][rows, slot], pool["src_class"][rows, slot] = True, CLS_GPU
+    for k in ("dl_period", "dl_reqs", "dl_jitter"):
+        pool[k][rows, slot] = 0
+
+
+def with_gpu_slot(pool_batch):
+    """The program's `pool_batch`, then a GPU at slot `n_cpu` of every
+    mix."""
+    def wrapped(cfg, mixes):
+        pool, active = pool_batch(cfg, mixes)
+        pool = {k: np.array(v) for k, v in pool.items()}
+        plant_gpu(pool, slice(None), cfg.n_cpu, 0)
+        return pool, active
+    return wrapped
+
+
+def with_gpu_alone_rows(alone_batch):
+    """The program's `alone_batch`, then an alone row for each GPU of the
+    program's table that it lacks, the GPU at slot `n_cpu`."""
+    from repro.core import workloads as wl
+
+    def wrapped(cfg):
+        pool, active, amap = alone_batch(cfg)
+        gpus = [i for i, g in enumerate(wl.GPU_BENCH) if g[0] not in amap]
+        extra = {k: np.repeat(v[:1], len(gpus), axis=0)
+                 for k, v in pool.items()}
+        extra_active = np.zeros((len(gpus),) + active.shape[1:], bool)
+        amap = dict(amap)
+        for r, i in enumerate(gpus):
+            plant_gpu(extra, r, cfg.n_cpu, i)
+            extra_active[r, cfg.n_cpu] = True
+            amap[wl.GPU_BENCH[i][0]] = len(amap)
+        pool = {k: np.concatenate([pool[k], extra[k]]) for k in pool}
+        return pool, np.concatenate([active, extra_active]), amap
+    return wrapped
+
+
+def test_program_building_a_gpu_the_cell_lacks_is_caught(tmp_path,
+                                                         monkeypatch):
+    """A program that builds a GPU the cell does not have, planted over
+    the program's own builders (a GPU at slot `n_cpu` of every mix, GPU
+    alone rows), reads `correct: false` through `traffic_mismatch`, in the
+    check and in a whole run, whatever the program does with no GPU."""
+    from repro.core import params
+    from repro.core import workloads as wl
+
+    monkeypatch.setattr(wl, "pool_batch", with_gpu_slot(wl.pool_batch))
+    monkeypatch.setattr(wl, "alone_batch",
+                        with_gpu_alone_rows(wl.alone_batch))
     cell = cells.load_cell("soc.tiny", root=scratch_checkout(tmp_path))
     f = dict(cell.sim_fields)
     cfg = params.SimConfig(timing=params.Timing(**f.pop("timing")), **f)

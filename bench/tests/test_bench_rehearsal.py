@@ -163,13 +163,14 @@ def test_new_files_are_found_by_name(tmp_path):
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
 
     cell = cells.load_cell("soc16.qos", root=root)
+    fig4 = cells.load_cell(CELLS[0], root)
     assert cell.sim_fields["n_hwa"] == 2
     assert cell.traffic["population"]["n_per_cat"] == 2
-    assert cell.per_layer == {"sweeps_in_window": "sweeps"}
+    assert cell.per_layer == {**fig4.per_layer, "sweeps_in_window": "sweeps"}
     assert set(cell.end_to_end) == {"cycle_wl_per_s", "setup_s"}
     assert run.load_metric_reader("sweeps_in_window", root)({}) == 42.0
     assert cells.cycle_workloads(cell) == 2500 * (14 + 27) * 8
-    assert "sweeps_in_window" not in cells.load_cell(CELLS[0], root).per_layer
+    assert "sweeps_in_window" not in fig4.per_layer
     after = {p: p.read_bytes() for p in before}
     assert after == before
     mix = cells.population(cell, 5, 1)[0]
