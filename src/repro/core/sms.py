@@ -53,32 +53,49 @@ def sms_state(cfg: SimConfig) -> Dict[str, Any]:
     }
 
 
+def _at(table, idx, axis=-1):
+    """`table` read at `idx` along one small axis, which the result drops.
+
+    idx broadcasts against table's shape without `axis`, every index in
+    range. An `engine.small_lookup` select chain, not a gather: exact for
+    every dtype and as cheap as the elementwise work it fuses into.
+    """
+    table = jnp.moveaxis(table, axis, -1)
+    idx = jnp.broadcast_to(idx, table.shape[:-1])
+    return engine.small_lookup(table, idx[..., None])[..., 0]
+
+
 def _run_from_head(rows, banks, head, length, F):
     """Front same-(bank,row) run length of one FIFO per channel.
 
-    rows/banks: (C, F) slot arrays; head/length: (C,). Only used on the
-    rare pop-exhausted-a-batch path, for the single drained source per
-    channel — O(C·F), not O(C·S·F).
+    rows/banks: (C, F) slot arrays; head/length: (C,). O(C·F), for the
+    single drained source per channel; stage 2 computes it every cycle and
+    keeps it only where a pop exhausts a batch. The rotation is a select
+    chain and the run an unrolled running AND over the F slots.
     """
     idx = (head[:, None] + jnp.arange(F)) % F
-    rows_o = jnp.take_along_axis(rows, idx, axis=-1)
-    banks_o = jnp.take_along_axis(banks, idx, axis=-1)
+    rows_o = engine.small_lookup(rows, idx)
+    banks_o = engine.small_lookup(banks, idx)
     in_r = jnp.arange(F) < length[:, None]
     eq = (rows_o == rows_o[:, :1]) & (banks_o == banks_o[:, :1]) & in_r
-    return jnp.sum(jnp.cumprod(eq.astype(jnp.int32), axis=-1), axis=-1)
+    run = eq[:, 0]
+    n = run.astype(jnp.int32)
+    for k in range(1, F):
+        run = run & eq[:, k]
+        n = n + run.astype(jnp.int32)
+    return n
 
 
 def batch_info(cfg: SimConfig, sms: Dict[str, Any], t):
     """(C,S) arrays: batch_len (front same-(bank,row) run) and readiness.
 
     batch_len is the incrementally-maintained `front_run` counter; only the
-    head birth is gathered (O(C·S)), never the full FIFO contents.
+    head birth is read (O(C·S)), never the full FIFO contents.
     """
     batch_len = sms["front_run"]
     nonempty = sms["f_len"] > 0
     row_changed = batch_len < sms["f_len"]
-    head_birth = jnp.take_along_axis(
-        sms["f_birth"], sms["f_head"][..., None], axis=-1)[..., 0]  # (C,S)
+    head_birth = _at(sms["f_birth"], sms["f_head"])         # (C,S)
     aged = nonempty & (t - head_birth >= cfg.batch_age_cap)
     full = sms["f_len"] >= cfg.fifo_size
     ready = nonempty & (row_changed | aged | full)
@@ -87,15 +104,15 @@ def batch_info(cfg: SimConfig, sms: Dict[str, Any], t):
 
 def stage1_admit(cfg: SimConfig, st, sms, t):
     """Decentralized admission: every source pushes into its own FIFO."""
-    C, S, F = cfg.n_channels, cfg.n_src, cfg.fifo_size
+    C, F = cfg.n_channels, cfg.fifo_size
     st = dict(st)
     sms = dict(sms)
     ch = engine.channel_of(cfg, st["pend_bank"])            # (S,)
-    sidx = jnp.arange(S)
-    flen = sms["f_len"][ch, sidx]
+    on_ch = lambda a: _at(a, ch, axis=0)                    # a[ch, sidx]
+    flen = on_ch(sms["f_len"])
     room = flen < F
     do = st["pend_valid"] & room
-    head = sms["f_head"][ch, sidx]
+    head = on_ch(sms["f_head"])
     slot = (head + flen) % F
     new_bank = engine.bank_in_channel(cfg, st["pend_bank"])
     # each source maps to exactly one channel this cycle: one-hot masked
@@ -106,10 +123,11 @@ def stage1_admit(cfg: SimConfig, st, sms, t):
     # front_run: a push extends the front batch only when the whole FIFO is
     # that batch (front_run == f_len) and the new request matches its
     # (bank, row); a push into an empty FIFO starts a run of 1
-    fr = sms["front_run"][ch, sidx]
-    extend = (fr == flen) & \
-        (st["pend_row"] == sms["f_row"][ch, sidx, head]) & \
-        (new_bank == sms["f_bank"][ch, sidx, head])
+    fr = on_ch(sms["front_run"])
+    # a[ch, sidx, head]: every FIFO's head slot, then the source's channel
+    at_head = lambda a: on_ch(_at(a, sms["f_head"]))
+    extend = (fr == flen) & (st["pend_row"] == at_head(sms["f_row"])) & \
+        (new_bank == at_head(sms["f_bank"]))
     new_fr = jnp.where(flen == 0, 1, jnp.where(extend, fr + 1, fr))
     sms["front_run"] = jnp.where(mask_cs, new_fr[None, :],
                                  sms["front_run"])
@@ -124,8 +142,7 @@ def stage1_admit(cfg: SimConfig, st, sms, t):
 
 def stage2_drain(cfg: SimConfig, pool, st, sms, t):
     """Pick ready batches (SJF w.p. p / RR w.p. 1-p) and drain 1 req/cycle."""
-    C, S, F = cfg.n_channels, cfg.n_src, cfg.fifo_size
-    B, D = cfg.n_banks, cfg.dcs_size
+    S, F, D = cfg.n_src, cfg.fifo_size, cfg.dcs_size
     sms = dict(sms)
     batch_len, ready = batch_info(cfg, sms, t)
 
@@ -170,42 +187,41 @@ def stage2_drain(cfg: SimConfig, pool, st, sms, t):
     sms["drain_src"] = jnp.where(start, pick.astype(jnp.int32),
                                  sms["drain_src"])
     sms["drain_left"] = jnp.where(
-        start, batch_len[jnp.arange(C), pick], sms["drain_left"])
+        start, _at(batch_len, pick), sms["drain_left"])
     sms["rr_ptr"] = jnp.where(start & ~use_sjf, (pick + 1) % S,
                               sms["rr_ptr"]).astype(jnp.int32)
 
     # --- drain one request per channel into the DCS ---
     draining = sms["drain_left"] > 0
     s = jnp.clip(sms["drain_src"], 0, S - 1)                # (C,)
-    cidx = jnp.arange(C)
-    head = sms["f_head"][cidx, s]
-    row = sms["f_row"][cidx, s, head]
-    bank = sms["f_bank"][cidx, s, head]
-    birth = sms["f_birth"][cidx, s, head]
-    has_req = sms["f_len"][cidx, s] > 0
+    head = _at(sms["f_head"], s)
+    flen = _at(sms["f_len"], s)
+    # the drained source's FIFO on each channel, (C, F), then its head slot
+    rows, banks, births = (_at(sms[k], s[:, None], axis=1)
+                           for k in ("f_row", "f_bank", "f_birth"))
+    row, bank, birth = _at(rows, head), _at(banks, head), _at(births, head)
+    has_req = flen > 0
     # safety: a desynced drain counter on an empty FIFO must not deadlock
     sms["drain_left"] = jnp.where(draining & ~has_req, 0, sms["drain_left"])
-    dcs_room = sms["d_len"][cidx, bank] < D
+    dlen = _at(sms["d_len"], bank)
+    dcs_room = dlen < D
     do = draining & has_req & dcs_room
     # pop stage-1
     new_head = jnp.where(do, (head + 1) % F, head)
-    new_len = sms["f_len"][cidx, s] - do.astype(jnp.int32)
+    new_len = flen - do.astype(jnp.int32)
     sms["f_head"] = engine.masked_set(sms["f_head"], s, new_head, do)
     sms["f_len"] = engine.masked_add(sms["f_len"], s, -1, do)
     sms["drain_left"] = sms["drain_left"] - do.astype(jnp.int32)
     # front_run: the pop shortens the front batch by one; when it exhausts
     # the batch with requests left, rescan just this source's FIFO (O(C·F))
     # for the next batch's run length
-    fr = sms["front_run"][cidx, s] - do.astype(jnp.int32)
+    fr = _at(sms["front_run"], s) - do.astype(jnp.int32)
     rescan = do & (fr == 0) & (new_len > 0)
-    fr = jnp.where(rescan,
-                   _run_from_head(sms["f_row"][cidx, s],
-                                  sms["f_bank"][cidx, s],
-                                  new_head, new_len, F),
+    fr = jnp.where(rescan, _run_from_head(rows, banks, new_head, new_len, F),
                    fr)
     sms["front_run"] = engine.masked_set(sms["front_run"], s, fr, do)
     # push stage-3
-    dslot = (sms["d_head"][cidx, bank] + sms["d_len"][cidx, bank]) % D
+    dslot = (_at(sms["d_head"], bank) + dlen) % D
     wr = lambda a, v: engine.masked_set2(a, bank, dslot, v, do)
     sms["d_row"] = wr(sms["d_row"], row)
     sms["d_src"] = wr(sms["d_src"], s.astype(jnp.int32))
@@ -222,9 +238,8 @@ def stage3_issue(cfg: SimConfig, st, sms, dram, t):
     """
     C, B, D = cfg.n_channels, cfg.n_banks, cfg.dcs_size
     sms = dict(sms)
-    cidx = jnp.arange(C)
     head = sms["d_head"]                                    # (C,B)
-    at_head = lambda a: jnp.take_along_axis(a, head[..., None], 2)[..., 0]
+    at_head = lambda a: _at(a, head)
     row = at_head(sms["d_row"])                             # (C,B)
     src = at_head(sms["d_src"])
     birth = at_head(sms["d_birth"])
@@ -234,12 +249,12 @@ def stage3_issue(cfg: SimConfig, st, sms, dram, t):
     rr_key = jnp.where(elig, (jnp.arange(B)[None, :]
                               - sms["rr_bank"][:, None]) % B, 1 << 28)
     pick = jnp.argmin(rr_key, axis=1)                       # (C,)
-    at_pick = lambda a: jnp.take_along_axis(a, pick[:, None], 1)[:, 0]
+    at_pick = lambda a: _at(a, pick)
     do = at_pick(elig)
     dram, st = engine.issue_channels(
         cfg, dram, st, do, pick, at_pick(row), at_pick(src), at_pick(birth),
         at_pick(lat), at_pick(is_hit), t)
-    head_p = head[cidx, jnp.where(do, pick, 0)]
+    head_p = _at(head, jnp.where(do, pick, 0))
     sms["d_head"] = engine.masked_set(sms["d_head"], pick, (head_p + 1) % D,
                                       do)
     sms["d_len"] = engine.masked_add(sms["d_len"], pick, -1, do)
@@ -268,7 +283,7 @@ def next_stage_event(cfg: SimConfig, st, sms, dram, t):
     t1 = t + 1
     # stage 1: a pending register with FIFO room pushes next cycle
     ch = engine.channel_of(cfg, st["pend_bank"])             # (S,)
-    room = sms["f_len"][ch, jnp.arange(cfg.n_src)] < cfg.fifo_size
+    room = _at(sms["f_len"], ch, axis=0) < cfg.fifo_size
     w1 = jnp.where(jnp.any(st["pend_valid"] & room), t1, INF)
     # stage 2: an active drain moves (or settles) every cycle; an idle
     # channel starts as soon as any batch is ready
@@ -277,17 +292,14 @@ def next_stage_event(cfg: SimConfig, st, sms, dram, t):
     act = jnp.any(~idle) | jnp.any(idle & jnp.any(ready, axis=-1))
     w2 = jnp.where(act, t1, INF)
     # aging: a nonempty, not-yet-ready FIFO turns ready at head_birth + cap
-    head_birth = jnp.take_along_axis(
-        sms["f_birth"], sms["f_head"][..., None], axis=-1)[..., 0]  # (C,S)
+    head_birth = _at(sms["f_birth"], sms["f_head"])         # (C,S)
     w_age = jnp.min(jnp.where(
         (sms["f_len"] > 0) & ~ready,
         jnp.maximum(head_birth + cfg.batch_age_cap, t1), INF))
     # stage 3: DCS head issue-eligibility times (inverts the three
     # `engine.eligibility` gates; their inputs are frozen while no issue
     # lands, which the witness itself guarantees for the span)
-    at_head = lambda a: jnp.take_along_axis(a, sms["d_head"][..., None],
-                                            2)[..., 0]        # (C,B)
-    row = at_head(sms["d_row"])
+    row = _at(sms["d_row"], sms["d_head"])                   # (C,B)
     openv = dram["open_valid"]
     is_hit = openv & (dram["open_row"] == row)
     lat = jnp.where(is_hit, tm.lat_hit,
@@ -347,14 +359,12 @@ def audit_skip(cfg: SimConfig, st, sms: Dict[str, Any], dram, t, t_new):
     u = t_new - 1
     skipped = t_new - t > 1
     ch = engine.channel_of(cfg, st["pend_bank"])
-    room = sms["f_len"][ch, jnp.arange(cfg.n_src)] < cfg.fifo_size
+    room = _at(sms["f_len"], ch, axis=0) < cfg.fifo_size
     s1 = jnp.any(st["pend_valid"] & room)
     _, ready = batch_info(cfg, sms, u)
     idle = sms["drain_left"] <= 0
     s2 = jnp.any(~idle) | jnp.any(idle & jnp.any(ready, axis=-1))
-    at_head = lambda a: jnp.take_along_axis(a, sms["d_head"][..., None],
-                                            2)[..., 0]
-    row = at_head(sms["d_row"])
+    row = _at(sms["d_row"], sms["d_head"])
     valid = sms["d_len"] > 0
     banks = jnp.broadcast_to(jnp.arange(cfg.n_banks), row.shape)
     elig, _, _ = engine.eligibility(cfg, dram, banks, row, valid, u)

@@ -13,6 +13,8 @@ the trace:
   * the stacked step looks up its small per-bank and per-source tables in
     `select.eligibility` and `select.score` with one-hot selects, never a
     gather;
+  * the SMS stages read their small FIFO and DCS tables with one-hot
+    selects too: no gather and no cumprod under `sms.stage1`-`3`;
   * the scan carry holds only cycle-varying state: the read-only workload
     parameters `_pool`/`_active` are closed over, not carried;
   * the refactor is bit-identical: the golden digests for atlas/parbs/tcm
@@ -128,6 +130,25 @@ def test_stacked_select_lookups_build_no_gather(policy_name):
     bad = [stack for p, stack in prims if p == "gather"
            and GATHER_FREE_SCOPES & set(stack.split("/"))]
     assert not bad, f"{policy_name}: {len(bad)} gather(s): {bad}"
+
+
+SMS_STAGES = {"sms.stage1", "sms.stage2", "sms.stage3"}
+
+
+@pytest.mark.parametrize("policy_name", ["sms", "sms_dash"])
+def test_sms_stage_lookups_build_no_gather(policy_name):
+    """Every small-axis read of the SMS stages is a one-hot select and the
+    front-run rescan an unrolled running AND: the per-cycle step has no
+    gather and no cumprod under `sms.stage1`-`sms.stage3`, while the walk
+    still sees the scatter-add that `engine.issue_channels` builds there."""
+    prims = list(_scoped_prims(_step_jaxpr(policy_name).jaxpr))
+    in_stage = [(p, stack) for p, stack in prims
+                if SMS_STAGES & set(stack.split("/"))]
+    assert SMS_STAGES <= {c for _, stack in in_stage
+                          for c in stack.split("/")}
+    assert any(p == "scatter-add" for p, _ in in_stage)
+    bad = [(p, stack) for p, stack in in_stage if p in ("gather", "cumprod")]
+    assert not bad, f"{policy_name}: {bad}"
 
 
 def test_energy_accounting_adds_no_sorts_or_scatters():

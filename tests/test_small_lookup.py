@@ -1,14 +1,17 @@
 """`engine.small_lookup` equals plain indexing, bit for bit, and builds no
-gather: bool, int32 and f32 tables (-0.0 and NaN payloads included), 8 and
-17 entries, indices of shape (E,) and (C, E), a table shared across the
-leading axes, and under `jax.vmap` over two leading axes."""
+gather: bool, int32 and f32 tables (-0.0 and NaN payloads included), 4, 6,
+8 and 17 entries, indices of shape (E,) and (C, E), a table shared across
+the leading axes, and under `jax.vmap` over two leading axes. So does
+`sms._at`, the SMS stages' read along one small axis of a FIFO or DCS
+table: that axis first, in the middle or last, with an index broadcast
+over the table's other axes."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import compat
-from repro.core import engine
+from repro.core import engine, sms
 
 C, E, P, R = 4, 134, 3, 2
 # quiet NaN with a payload; the select chain must carry its bits through
@@ -45,11 +48,22 @@ CASES = {
     "vmap2": ((P, R, C), (P, R, C, E),
               jax.vmap(jax.vmap(engine.small_lookup)),
               lambda t, i: np.take_along_axis(t, i, -1)),
+    # sms._at drops the small axis: out[...] = table[..., idx[...]] with
+    # the table's entries moved to the axis `_at` is told to read
+    "at_axis0": ((E,), (E,),
+                 lambda t, i: sms._at(jnp.moveaxis(t, -1, 0), i, axis=0),
+                 lambda t, i: np.take_along_axis(t, i[:, None], -1)[:, 0]),
+    "at_axis1": ((C, E), (C, E),
+                 lambda t, i: sms._at(jnp.moveaxis(t, -1, 1), i, axis=1),
+                 lambda t, i: np.take_along_axis(t, i[..., None], -1)[..., 0]),
+    "at_shared": ((C, E), (E,), sms._at,
+                  lambda t, i: np.take_along_axis(
+                      t, np.broadcast_to(i, (C, E))[..., None], -1)[..., 0]),
 }
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
-@pytest.mark.parametrize("k", [8, 17])
+@pytest.mark.parametrize("k", [4, 6, 8, 17])
 @pytest.mark.parametrize("dtype", ["bool", "int32", "float32"])
 def test_small_lookup_is_exact_indexing(dtype, k, case):
     lead, ishape, fn, ref = CASES[case]

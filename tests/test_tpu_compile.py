@@ -2,8 +2,9 @@
 
 Compiles `simulator._sim_batch` at the headline shape (parity config, 105
 mixes plus the alone baselines, 16k+2k cycles), and the stacked family
-program `simulator._sim_batch_stacked` at the benchmark cell's shape (16
-CPUs + 1 GPU on 4 channels, 134 entries, 128 rows, six policies), for one
+program `simulator._sim_batch_stacked` and the two SMS programs at the
+benchmark cell's shape (16 CPUs + 1 GPU on 4 channels, 134 entries, 128
+rows; six policies stacked, `sms` and `sms_dash` one each), for one
 chip of a described v5e:2x2 topology: nothing runs, but the TPU compiler
 must accept each program and its memory must fit the chip. The topology is
 described inside a module fixture (never at import, in a skipif or in
@@ -99,4 +100,28 @@ def test_stacked_family_compiles_for_v5e_without_select_gathers(one_chip):
     assert gathers                          # select.issue still gathers
     bad = [o for o in gathers
            if {"select.eligibility", "select.score"} & set(o.split("/"))]
+    assert not bad, bad
+
+
+@pytest.mark.parametrize("policy", ["sms", "sms_dash"])
+def test_sms_program_compiles_for_v5e_without_stage_gathers(one_chip,
+                                                            policy):
+    """The benchmark cell's SMS programs: each fits the chip, and its
+    optimized HLO keeps no gather and no reduce-window under
+    `sms.stage1`-`sms.stage3` (their FIFO and DCS reads are one-hot
+    selects, the front-run rescan a running AND)."""
+    cfg = common.parity_config(n_cpu=16, n_channels=4)
+    cfg, pool, active = _headline_args(one_chip, cfg)
+    assert active.shape[0] == 128
+    compiled = sim._sim_batch.lower(cfg, policy, 2_000, 500,
+                                    sim.DEFAULT_UNROLL, False, pool,
+                                    active).compile()
+    _assert_fits(compiled)
+    hlo = compiled.as_text()
+    stages = {"sms.stage1", "sms.stage2", "sms.stage3"}
+    op_names = re.findall(r'op_name="([^"]*)"', hlo)
+    assert stages <= {c for o in op_names for c in o.split("/")}
+    bad = [m.group(0)[:160] for m in re.finditer(
+        r'= \S+ (?:gather|reduce-window)\(.*?op_name="([^"]*)"', hlo)
+        if stages & set(m.group(1).split("/"))]
     assert not bad, bad
